@@ -132,6 +132,9 @@ def bench_scaling(t: Table):
         # Below 4 shards the bound would be >= the full width — leave it off.
         mrps = 2 * lanes // n_dev if n_dev >= 4 else 0
         env = dict(os.environ)
+        # the children emulate n_dev devices on the host CPU by design; they
+        # never touch an accelerator this process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
         env["PYTHONPATH"] = str(repo / "src")
         out = subprocess.run(
@@ -142,12 +145,12 @@ def bench_scaling(t: Table):
         )
         line = [l for l in out.stdout.splitlines() if l.startswith("RESULT")]
         if not line:
-            t.add(f"fig13/scaling_dev{n_dev}", 0.0, f"FAILED: {out.stderr[-200:]}")
-            continue
+            raise RuntimeError(f"scaling child ({n_dev} devices) failed: "
+                               f"{out.stderr[-400:]}")
         us, sps, proj, xchg, idb, rowb, imb, moves, loss, hist = line[0].split()[1:11]
         t.add(
             f"fig13/scaling_dev{n_dev}", float(us),
-            f"samples_per_s={sps} samples_per_s_parallel_projected={proj} "
+            f"CPU host: samples_per_s={sps} samples_per_s_parallel_projected={proj} "
             f"exchange_bytes_per_step={xchg} "
             f"id_leg_bytes_per_step={idb} row_leg_bytes_per_step={rowb} "
             f"shard_imbalance={imb} rebalance_moves={moves} loss={loss} "

@@ -16,6 +16,7 @@ import datetime
 import json
 import pathlib
 import subprocess
+import sys
 
 
 def _git_sha() -> str:
@@ -73,14 +74,16 @@ def main() -> None:
 
     t = Table()
     print("name,us_per_call,derived")
+    failed = []
     for fn in fns:
         if args.only and args.only not in f"{fn.__module__}.{fn.__name__}":
             continue
         try:
             fn(t)
-        except Exception as e:  # keep the harness running; report the failure
+        except Exception as e:  # finish the other benches, then exit non-zero
             if args.strict:
                 raise
+            failed.append(fn.__name__)
             t.add(f"{fn.__name__}/ERROR", 0.0, f"{type(e).__name__}: {e}")
     out = pathlib.Path(__file__).resolve().parents[1] / "results" / "bench.csv"
     out.parent.mkdir(exist_ok=True)
@@ -88,6 +91,8 @@ def main() -> None:
         f"{n},{u:.1f},{d}" for n, u, d in t.rows) + "\n")
     if args.json:
         append_json_record(pathlib.Path(args.json), t.rows, SMOKE)
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

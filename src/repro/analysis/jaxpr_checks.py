@@ -26,6 +26,7 @@ import re
 from typing import Any, Iterator, List, Tuple
 
 import jax
+from jax.extend import core as jax_core
 import numpy as np
 from jax.api_util import shaped_abstractify
 
@@ -61,9 +62,9 @@ def _sub_jaxprs(params: dict) -> Iterator[Any]:
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for x in vals:
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, jax_core.ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, jax_core.Jaxpr):
                 yield x
 
 
@@ -94,7 +95,7 @@ def check_host_transfer(case: SmokeCase, c: Contract) -> List[Violation]:
         # (e.g. ``jnp.unique(..., fill_value=<int>)``) — XLA folds it; only a
         # device_put of a traced/captured value is a real mid-graph transfer.
         if eqn.primitive.name == "device_put" and all(
-            isinstance(v, jax.core.Literal) for v in eqn.invars
+            isinstance(v, jax_core.Literal) for v in eqn.invars
         ):
             continue
         out.append(

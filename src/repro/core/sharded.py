@@ -61,11 +61,13 @@ Scaling the exchange (the three fronts that keep throughput monotone in S):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.random import threefry2x32_p
 
 from repro.analysis.contracts import INT_COUNTERS, contract
 from repro.core import cache as cache_lib
@@ -103,12 +105,55 @@ __all__ = [
     "ShardedCollectionPlan",
     "ShardedEmbeddingCollection",
     "flat_store",
+    "row_counters",
+    "uniform_rows",
 ]
 
 # sentinel for invalid lanes in the dedup'd rank buffer: sorts after every
 # real rank (vocab is far below int32 max), so ``jnp.unique`` packs real
 # ranks first and padding last.
 _PAD_RANK = jnp.iinfo(jnp.int32).max
+
+
+def _draws_by_counter(dtype) -> bool:
+    """``uniform_rows`` reproduces ``jax.random.uniform`` only for float32
+    under JAX's partitionable threefry (its default)."""
+    return bool(jax.config.jax_threefry_partitionable) and jnp.dtype(dtype) == jnp.float32
+
+
+def row_counters(rows: jnp.ndarray, dim: int):
+    """``(hi, lo)`` uint32 words of the 64-bit counters ``r * dim + j`` of
+    rows ``rows`` (shape ``rows.shape + (dim,)``), from 16-bit limbs of r."""
+    if not 0 < dim < 1 << 16:
+        raise ValueError(f"dim {dim} outside the 16-bit counter split")
+    r = rows.astype(jnp.uint32)[..., None]
+    j = jnp.arange(dim, dtype=jnp.uint32)
+    d = jnp.uint32(dim)
+    p_lo = (r & 0xFFFF) * d
+    p_hi = (r >> 16) * d
+    t = (p_hi & 0xFFFF) << 16
+    s1 = t + p_lo
+    lo = s1 + j
+    hi = (p_hi >> 16) + (s1 < t).astype(jnp.uint32) + (lo < s1).astype(jnp.uint32)
+    return hi, lo
+
+
+@functools.partial(jax.jit, static_argnames=("dim",))
+def uniform_rows(key, rows: jnp.ndarray, dim: int, minval: float, maxval: float):
+    """Rows ``rows`` (int32, any shape) of ``jax.random.uniform(key, (vocab,
+    dim), jnp.float32, minval, maxval)``, bit for bit, without drawing the
+    others.  Partitionable threefry draws element ``(r, j)`` from the 64-bit
+    counter ``r * dim + j``, so each device can draw just the rows it holds.
+    Jitted like ``jax.random.uniform``, so both compile the same arithmetic."""
+    data = jax.random.key_data(key) if jax.dtypes.issubdtype(
+        key.dtype, jax.dtypes.prng_key) else key
+    hi, lo = row_counters(rows, dim)
+    b1, b2 = threefry2x32_p.bind(data[0], data[1], hi, lo)
+    # jax.random.uniform's float32 transform of the bits
+    one = jnp.asarray(np.array(1.0, np.float32).view(np.uint32))
+    floats = jax.lax.bitcast_convert_type(((b1 ^ b2) >> 9) | one, jnp.float32) - 1.0
+    lo_v, hi_v = jnp.float32(minval), jnp.float32(maxval)
+    return jnp.maximum(lo_v, floats * (hi_v - lo_v) + lo_v)
 
 
 def flat_store(store: HostStore) -> HostStore:
@@ -438,9 +483,7 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
             )
         for sname, spec in self.cached_slabs.items():
             scale = 1.0 / np.sqrt(spec.dim)
-            weight = jax.random.uniform(
-                next(kit), (spec.vocab, spec.dim), spec.dtype, -scale, scale
-            )
+            wkey = next(kit)  # the slab's draw, uniform(wkey, (vocab, dim))
             slab_counts = None
             counts_ranked = None
             if counts is not None:
@@ -507,11 +550,42 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
             vs = self.rows_per_shard(spec)
             # scatter rank r's row to flat slot owner[r]*vs + local[r]; pad
             # rows (flat slots no rank maps to) stay zero and are never read.
-            dest = jnp.asarray(
-                assign.owner.astype(np.int64) * vs + assign.local.astype(np.int64),
-                jnp.int32,
-            )
-            flat = jnp.zeros((S * vs, spec.dim), spec.dtype).at[dest].set(weight)
+            dest = assign.owner.astype(np.int64) * vs + assign.local.astype(np.int64)
+            if counts is None:
+                # round-robin over the sequence routed ranks K.. then the
+                # replicated head: rank r sits at position p = (r - K) mod
+                # vocab, on shard p % S, row p // S.  The rank maps are iotas
+                # rather than vocab-sized constants in a jitted init.
+                pos = (jnp.arange(spec.vocab, dtype=jnp.int32) + (spec.vocab - K)) % spec.vocab
+                rank_owner, rank_local = pos % S, pos // S
+            else:
+                rank_owner = jnp.asarray(assign.owner)
+                rank_local = jnp.asarray(assign.local)
+            if _draws_by_counter(spec.dtype):
+                # ``at[s, l]`` is the rank whose home is shard s's row l
+                # (vocab on pad rows); each row is drawn where it lives, so
+                # no device draws, or holds, the whole table
+                if counts is None:
+                    p = (jnp.arange(vs, dtype=jnp.int32)[None, :] * S
+                         + jnp.arange(S, dtype=jnp.int32)[:, None])
+                    at = jnp.where(p < spec.vocab, (p + K) % spec.vocab, spec.vocab)
+                else:
+                    inv = np.full((S * vs,), spec.vocab, np.int32)
+                    inv[dest] = np.arange(spec.vocab, dtype=np.int32)
+                    at = jnp.asarray(inv.reshape(S, vs))
+                at = dist_part.constrain(at, "shard", None)
+                rows = uniform_rows(wkey, at, spec.dim, -scale, scale)
+                rows = jnp.where((at < spec.vocab)[..., None], rows, 0)
+                flat = rows.reshape(S * vs, spec.dim)
+                head = uniform_rows(wkey, jnp.arange(K, dtype=jnp.int32), spec.dim,
+                                    -scale, scale)
+            else:
+                weight = jax.random.uniform(
+                    wkey, (spec.vocab, spec.dim), spec.dtype, -scale, scale
+                )
+                flat = jnp.zeros((S * vs, spec.dim), spec.dtype).at[
+                    jnp.asarray(dest, jnp.int32)].set(weight)
+                head = weight[:K]
             store = HostStore.create({"weight": flat}, codec=codec)
             full = HostStore(
                 data={k: v.reshape((S, vs) + v.shape[1:]) for k, v in store.data.items()},
@@ -532,11 +606,11 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
                 full, cache = jax.vmap(
                     lambda f, c: cache_lib.warmup(ccfg, f, c)
                 )(full, cache)
-            # replicated hot head: rank r's content is weight[r] (the same
-            # rank-content convention the flat scatter above follows), so the
-            # arena starts bit-identical to the ranks' slow-tier homes.
+            # replicated hot head: rank r's content is row r of the slab's
+            # draw (the same rank-content convention as the homes above), so
+            # the arena starts bit-identical to the ranks' slow-tier homes.
             rep = RepArena(
-                rows=weight[:K],
+                rows=head,
                 score=jnp.zeros((K,), jnp.float32),
                 last_touch=jnp.zeros((K,), jnp.int32),
                 step=jnp.zeros((), jnp.int32),
@@ -545,8 +619,8 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
                 full=full,
                 cache=cache,
                 idx_map=idx_map,
-                rank_owner=jnp.asarray(assign.owner),
-                rank_local=jnp.asarray(assign.local),
+                rank_owner=rank_owner,
+                rank_local=rank_local,
                 routed_lanes=jnp.zeros((S,), jnp.int32),
                 rep=rep,
             )

@@ -3,10 +3,9 @@ fused dedup -> residency-probe -> slot-assign, fused arena gather+decode.
 
 ``ops`` holds the dispatching entry points (Pallas on accelerators,
 bit-identical XLA references on CPU); ``ref`` the XLA implementations;
-``kernel`` the Pallas bodies (interpret-mode capable for CPU CI).
+``kernel`` the Pallas bodies (compiled on TPU, interpreted on CPU).
 """
 from repro.kernels.cache_ops.ops import (
-    INTERPRET,
     arena_gather,
     chunked_move,
     kernels_enabled,
@@ -17,7 +16,6 @@ from repro.kernels.cache_ops.ops import (
 from repro.kernels.cache_ops.ref import PlanImage
 
 __all__ = [
-    "INTERPRET",
     "PlanImage",
     "arena_gather",
     "chunked_move",

@@ -5,9 +5,10 @@ Two layers:
 * ``*_impl`` functions — UN-jitted, called inline from ``cache.plan_prepare``
   / ``sharded.plan_prepare`` / ``ArenaStore.gather_slots`` so they trace into
   the caller's jaxpr (the analyzer's sort-bound pass sees through them).  On
-  CPU they run the XLA references from ``ref.py``; on TPU/GPU (or under
-  ``REPRO_FORCE_PALLAS_CACHE_OPS=1``, which the interpret-mode CI smokes set)
-  the capacity-streaming pieces lower through the Pallas kernels.
+  CPU they run the XLA references from ``ref.py``; on TPU the
+  capacity-streaming pieces compile through the Pallas kernels.  On CPU,
+  ``REPRO_FORCE_PALLAS_CACHE_OPS=1`` (the interpret-mode CI smokes) routes
+  them through the kernels in the Pallas interpreter.
 * registered jit wrappers below — the analyzer/bench surface.  Each carries a
   ``@contract`` whose ``max_sort_size`` pins the bounded-top-K claim: at the
   smoke geometry nothing here may sort more than the unique buffer.
@@ -29,10 +30,7 @@ from repro.kernels.cache_ops import kernel as _kernel
 from repro.kernels.cache_ops import ref as _ref
 from repro.kernels.cache_ops.ref import PlanImage
 
-INTERPRET = True  # flip to False on real TPU
-
 __all__ = [
-    "INTERPRET",
     "PlanImage",
     "arena_gather",
     "arena_gather_impl",
@@ -51,11 +49,13 @@ __all__ = [
 
 
 def kernels_enabled() -> bool:
-    """Pallas lowering: on for accelerator backends, forceable for CPU CI
-    (interpret mode) via ``REPRO_FORCE_PALLAS_CACHE_OPS=1``."""
-    if os.environ.get("REPRO_FORCE_PALLAS_CACHE_OPS") == "1":
+    """Pallas route: on for the TPU (compiled), forceable on CPU via
+    ``REPRO_FORCE_PALLAS_CACHE_OPS=1`` (interpret mode, for tests).  Whether
+    a kernel compiles or interprets follows the backend alone
+    (``repro.kernels.interpret_mode``), never this flag."""
+    if jax.default_backend() == "tpu":
         return True
-    return jax.default_backend() in ("tpu", "gpu")
+    return os.environ.get("REPRO_FORCE_PALLAS_CACHE_OPS") == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -68,7 +68,7 @@ def victim_topk_impl(key: jnp.ndarray, kv: int) -> jnp.ndarray:
     ``jnp.argsort(key, descending=True)[:kv].astype(int32)``."""
     if kernels_enabled():
         u = _ref.ordered_u32(key)
-        t, n_gt = _kernel.victim_threshold_pallas(u, kv, interpret=INTERPRET)
+        t, n_gt = _kernel.victim_threshold_pallas(u, kv)
         # select + order epilogue shared with the reference route
         return _ref.topk_select(u, t, n_gt, key, kv)
     return _ref.victim_topk(key, kv)
@@ -92,7 +92,7 @@ def plan_image_impl(rows, row_to_slot, k: int) -> PlanImage:
 
 def bucketize_impl(owner, local, num_shards: int) -> jnp.ndarray:
     if kernels_enabled():
-        return _kernel.bucketize_pallas(owner, local, num_shards, interpret=INTERPRET)
+        return _kernel.bucketize_pallas(owner, local, num_shards)
     return _ref.bucketize(owner, local, num_shards)
 
 
@@ -110,7 +110,7 @@ def arena_gather_impl(
     kernel does not special-case)."""
     if kernels_enabled() and codec in ("fp16", "int8") and head.ndim == 2:
         return _kernel.gather_decode_pallas(
-            head, tail, sideband, slots, codec, out_dtype, interpret=INTERPRET
+            head, tail, sideband, slots, codec, out_dtype
         )
     return _ref.arena_gather(head, tail, sideband, slots, decode, out_dtype)
 

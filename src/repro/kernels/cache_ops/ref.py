@@ -19,8 +19,8 @@ O(capacity)-sorted:
   ``ArenaStore.gather_slots`` share a single fusable body.
 
 These run as the CPU fast path; the Pallas kernels in ``kernel.py`` lower
-the same math for accelerators and are verified bit-identical against this
-module in interpret mode.
+the same math for the TPU and are verified bit-identical against this
+module in interpret mode (tests) and compiled (``chip_smoke.py``).
 """
 from __future__ import annotations
 

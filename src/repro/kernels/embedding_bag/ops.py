@@ -17,9 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.contracts import contract
-from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
-
-INTERPRET = True  # flip to False on real TPU
+from repro.kernels.embedding_bag.kernel import embedding_bag_chunked
 
 
 def densify(flat_ids: jnp.ndarray, segment_ids: jnp.ndarray, num_segments: int, max_bag: int):
@@ -36,7 +34,7 @@ def densify(flat_ids: jnp.ndarray, segment_ids: jnp.ndarray, num_segments: int, 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _embedding_bag(table, flat_ids, segment_ids, num_segments, combiner, max_bag):
     dense = densify(flat_ids, segment_ids, num_segments, max_bag)
-    out = embedding_bag_pallas(table, dense, interpret=INTERPRET)
+    out = embedding_bag_chunked(table, dense)
     if combiner == "mean":
         valid = jnp.sum((dense >= 0).astype(jnp.float32), axis=1)
         out = out / jnp.maximum(valid, 1)[:, None].astype(out.dtype)

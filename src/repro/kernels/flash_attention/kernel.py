@@ -23,6 +23,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -80,7 +82,7 @@ def flash_attention_pallas(
     window: Optional[int] = None,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: bool = True,  # CPU container: validate in interpret mode
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
@@ -110,5 +112,5 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

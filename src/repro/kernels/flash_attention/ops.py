@@ -13,12 +13,10 @@ from repro.analysis.contracts import contract
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
 
-INTERPRET = True  # flip to False on real TPU
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _attn(q, k, v, causal, window):
-    return flash_attention_pallas(q, k, v, causal=causal, window=window, interpret=INTERPRET)
+    return flash_attention_pallas(q, k, v, causal=causal, window=window)
 
 
 def _attn_fwd(q, k, v, causal, window):

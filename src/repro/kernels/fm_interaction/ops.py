@@ -1,4 +1,4 @@
-"""Jit'd wrapper for the FM-interaction kernel (pads batch to block size)."""
+"""Jit'd wrapper for the FM-interaction kernel."""
 from __future__ import annotations
 
 import jax
@@ -7,16 +7,8 @@ import jax.numpy as jnp
 from repro.analysis.contracts import contract
 from repro.kernels.fm_interaction.kernel import fm_interaction_pallas
 
-INTERPRET = True  # flip to False on real TPU
-
 
 @contract(max_sort_size=0)
 @jax.jit
 def fm_interaction(v: jnp.ndarray) -> jnp.ndarray:
-    b = v.shape[0]
-    block = min(1024, b)
-    pad = (-b) % block
-    if pad:
-        v = jnp.concatenate([v, jnp.zeros((pad,) + v.shape[1:], v.dtype)], axis=0)
-    out = fm_interaction_pallas(v, block_b=block, interpret=INTERPRET)
-    return out[:b]
+    return fm_interaction_pallas(v)

@@ -16,12 +16,7 @@ __all__ = ["make_production_mesh", "make_mesh", "make_hybrid_mesh"]
 
 
 def _make(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    # so on older jax the plain call is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -137,8 +137,6 @@ def analyze_compiled(compiled, lowered_text: Optional[str] = None) -> Dict[str, 
     from repro.launch import hlo_analyzer
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     text = lowered_text if lowered_text is not None else compiled.as_text()
     c = hlo_analyzer.analyze_hlo(text)

@@ -14,6 +14,7 @@ from repro.serve.engine import ServeEngine
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.train import cache_policy
 
     ap = argparse.ArgumentParser()
@@ -42,6 +43,7 @@ def main():
                          "trace to <dir>/serve.trace.json; render with "
                          "`python -m repro.obs.report <dir>/serve.jsonl`")
     args = ap.parse_args()
+    enable_compile_cache()
     policy = cache_policy(args.cache_policy)
 
     if args.arch == "mind":

@@ -1,20 +1,38 @@
-"""Training launcher: ``--arch <id>`` selects a registry architecture and runs
-the fault-tolerant trainer on synthetic data (CPU-scale shapes by default;
-the production mesh path is exercised by ``launch.dryrun``).
+"""Training launcher: ``--arch <id>`` selects an architecture and runs the
+fault-tolerant trainer on synthetic data.
 
-  PYTHONPATH=src python -m repro.launch.train --arch dlrm-criteo --steps 50
+``dlrm-avazu`` and ``dlrm-criteo`` build the registry configs at their
+published widths (dim 128, the paper's MLPs, batch, 1.5% cache); ``dlrm``
+and the other recsys archs build small CPU-scale models.  With
+``--model-shards N`` on a host with at least N devices the cached slabs
+split over the ``model`` axis of a hybrid (data, model) mesh.
+
+  PYTHONPATH=src python -m repro.launch.train --arch dlrm --steps 50
+  PYTHONPATH=src python -m repro.launch.train --arch dlrm-avazu --steps 20
+  PYTHONPATH=src python -m repro.launch.train --arch dlrm-criteo --model-shards 4
   PYTHONPATH=src python -m repro.launch.train --arch din --steps 20
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import dataclasses
+import importlib
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+import repro.dist.partitioning as dist
 from repro.data import graphs, synth
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_hybrid_mesh
 from repro.train.trainer import PipelinedTrainer, Trainer, TrainerConfig
+
+# archs whose DLRM comes from a registry config at published widths
+PUBLISHED_DLRM = ("dlrm-avazu", "dlrm-criteo")
 
 
 def cache_policy(name):
@@ -24,7 +42,24 @@ def cache_policy(name):
     return Policy(name) if name else None
 
 
-def _recsys_runner(arch: str, batch: int, host_precision: str = "fp32",
+def dlrm_config(arch: str, batch=None, **cache_kw):
+    """``DLRMConfig`` for a dlrm arch: the registry ``CONFIG`` of
+    ``dlrm-avazu`` / ``dlrm-criteo`` (its batch unless ``batch`` is given),
+    or the small ``dlrm`` model; ``cache_kw`` sets the cache and sharding
+    fields on top."""
+    from repro.models.dlrm import DLRMConfig
+
+    if arch in PUBLISHED_DLRM:
+        cfg = importlib.import_module(f"repro.configs.{arch.replace('-', '_')}").CONFIG
+        if batch:
+            cfg = dataclasses.replace(cfg, batch_size=batch)
+        return dataclasses.replace(cfg, **cache_kw)
+    return DLRMConfig(vocab_sizes=(100_000, 50_000, 20_000), embed_dim=32,
+                      batch_size=batch or 512, cache_ratio=0.02, lr=0.3,
+                      bottom_mlp=(64, 32), top_mlp=(64,), **cache_kw)
+
+
+def recsys_runner(arch: str, batch=None, host_precision: str = "fp32",
                    model_shards: int = 0, policy=None,
                    replicate_top_k: int = 0, exchange_codec: str = "fp32",
                    max_routed_per_shard: int = 0,
@@ -38,23 +73,23 @@ def _recsys_runner(arch: str, batch: int, host_precision: str = "fp32",
         raise SystemExit("--replicate-top-k / --exchange-codec / "
                          "--max-routed-per-shard shape the sharded exchange; "
                          "they need --model-shards >= 1")
-    if arch.startswith("dlrm"):
-        from repro.models.dlrm import DLRM, DLRMConfig
+    if arch in ("dlrm",) + PUBLISHED_DLRM:
+        from repro.models.dlrm import DLRM
 
-        cfg = DLRMConfig(vocab_sizes=(100_000, 50_000, 20_000), embed_dim=32,
-                         batch_size=batch, cache_ratio=0.02, lr=0.3,
-                         bottom_mlp=(64, 32), top_mlp=(64,),
-                         host_precision=host_precision,
-                         arena_precision=arena_precision,
-                         use_pallas_plan=use_pallas_plan, chunk_rows=chunk_rows,
-                         model_shards=model_shards, policy=policy,
-                         replicate_top_k=replicate_top_k,
-                         exchange_codec=exchange_codec,
-                         max_routed_per_shard=max_routed_per_shard)
+        cfg = dlrm_config(arch, batch, host_precision=host_precision,
+                          arena_precision=arena_precision,
+                          use_pallas_plan=use_pallas_plan, chunk_rows=chunk_rows,
+                          model_shards=model_shards, policy=policy,
+                          replicate_top_k=replicate_top_k,
+                          exchange_codec=exchange_codec,
+                          max_routed_per_shard=max_routed_per_shard)
         model = DLRM(cfg)
-        spec = synth.ZipfSparseSpec(vocab_sizes=cfg.vocab_sizes, n_dense=13)
-        make = lambda s: {k: jnp.asarray(v) for k, v in synth.sparse_batch(spec, batch, 0, s).items()}
-    elif arch == "fm":
+        spec = synth.ZipfSparseSpec(vocab_sizes=cfg.vocab_sizes, n_dense=cfg.n_dense)
+        make = lambda s: {k: jnp.asarray(v) for k, v in synth.sparse_batch(
+            spec, cfg.batch_size, 0, s).items()}
+        return model, make, model.flush
+    batch = batch or 512
+    if arch == "fm":
         from repro.models.recsys_models import FMConfig, FMModel
 
         cfg = FMConfig(vocab_sizes=(100_000,) * 6, embed_dim=10, batch_size=batch,
@@ -94,11 +129,73 @@ def _recsys_runner(arch: str, batch: int, host_precision: str = "fp32",
     return model, make, model.flush
 
 
+def hybrid_shardings(model, mesh):
+    """``(state, batch)`` NamedSharding trees of the hybrid-parallel layout on
+    ``mesh``: every stacked collection leaf splits its shard axis over
+    ``model`` (``collection.shard_specs``), dense params and optimizer state
+    replicate, and the batch splits over ``data``."""
+    with dist.axis_rules(mesh, dist.hybrid_rules()):
+        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    specs = dict(jax.tree_util.tree_map(lambda _: P(), shapes),
+                 emb=model.collection.shard_specs())
+    batch = model.input_specs(model.cfg.batch_size)
+    bspecs = {k: P("data", *(None,) * (v.ndim - 1)) for k, v in batch.items()}
+    named = lambda t: jax.tree_util.tree_map(
+        lambda p: NamedSharding(mesh, p), t, is_leaf=lambda x: isinstance(x, P))
+    return named(specs), named(bspecs)
+
+
+@dataclasses.dataclass
+class Placement:
+    """Where the state and batches of a run live (built by ``place``)."""
+
+    init_fn: Callable[[], Any]
+    make_batch: Callable[[int], Any]
+    scope: Any  # logical-axis rule context every trace of the run runs under
+    state_sh: Any = None  # state NamedSharding tree; None on one device
+
+    def jit(self, fn, *, returns_metrics: bool = True, **kw):
+        """``jax.jit(fn, **kw)``.  On a mesh the state ``fn`` returns (alone,
+        or first of ``(state, metrics)``) keeps the state's layout, so the
+        next step, and its donation, sees the same shardings."""
+        if self.state_sh is not None:
+            kw["out_shardings"] = (self.state_sh, None) if returns_metrics else self.state_sh
+        return jax.jit(fn, **kw)
+
+    @property
+    def shard_fn(self) -> Optional[Callable[[Any], Any]]:
+        """Lays a restored state out like a fresh one (None on one device)."""
+        if self.state_sh is None:
+            return None
+        return lambda st: jax.device_put(st, self.state_sh)
+
+
+def place(model, make, model_shards: int = 0, mesh=None) -> Placement:
+    """Collection-backed models initialise under ``jax.jit``.  With
+    ``model_shards`` and a mesh (``make_hybrid_mesh(model_shards)`` when the
+    host has that many devices), init writes each shard straight to its
+    device, so no device ever holds a whole table, and the batch is put on
+    the ``data`` axis."""
+    key = jax.random.PRNGKey(0)
+    if not hasattr(model, "collection"):
+        return Placement(lambda: model.init(key), make, contextlib.nullcontext())
+    if model_shards and mesh is None and len(jax.devices()) >= model_shards:
+        mesh = make_hybrid_mesh(model_shards)
+    if mesh is None:
+        return Placement(lambda: jax.jit(model.init)(key), make, contextlib.nullcontext())
+    state_sh, batch_sh = hybrid_shardings(model, mesh)
+    init = jax.jit(model.init, out_shardings=state_sh)
+    return Placement(lambda: init(key), lambda s: jax.device_put(make(s), batch_sh),
+                     dist.axis_rules(mesh, dist.hybrid_rules()), state_sh)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--batch", type=int, default=512)
+    ap.add_argument("--batch", type=int, default=None,
+                    help="batch size (default: the config's own for "
+                         "dlrm-avazu / dlrm-criteo, 512 otherwise)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--pipeline-depth", type=int, default=0,
                     help="0 = serial; k >= 1 = pipelined groups of k steps per "
@@ -182,6 +279,7 @@ def main():
                          "only the last N step records in memory (the full "
                          "stream is on disk when --obs-dir is set)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "gatedgcn":
         from repro.models.gatedgcn import GatedGCNConfig, GatedGCNModel
@@ -204,7 +302,7 @@ def main():
             mod.SMOKE.vocab, 8, 64, 0, s).items()}
         flush = None
     else:
-        model, make, flush = _recsys_runner(args.arch, args.batch,
+        model, make, flush = recsys_runner(args.arch, args.batch,
                                             args.host_precision, args.model_shards,
                                             policy=cache_policy(args.cache_policy),
                                             replicate_top_k=args.replicate_top_k,
@@ -228,12 +326,14 @@ def main():
                        refresh_interval=args.refresh_interval or None,
                        obs_dir=args.obs_dir, obs_annotate=args.obs_annotate,
                        history_limit=args.history_limit or None)
+    where = place(model, make, args.model_shards)
     kw = dict(
-        init_fn=lambda: model.init(jax.random.PRNGKey(0)),
-        make_batch=make,
+        init_fn=where.init_fn,
+        make_batch=where.make_batch,
         flush_fn=flush,
         refresh_fn=refresh_fn,
         on_straggler=lambda s, dt: print(f"[straggler] step {s}: {dt*1e3:.0f} ms"),
+        shard_fn=where.shard_fn,
     )
     if args.pipeline_depth > 0:
         if not hasattr(model, "plan_step"):
@@ -246,15 +346,17 @@ def main():
         trainer = PipelinedTrainer(
             tc,
             plan_fn=jax.jit(model.plan_step),
-            compute_fn=jax.jit(model.compute_step, donate_argnums=(0,)),
-            apply_fn=jax.jit(model.apply_step, donate_argnums=(0,)),
+            compute_fn=where.jit(model.compute_step, donate_argnums=(0,)),
+            apply_fn=where.jit(model.apply_step, returns_metrics=False,
+                               donate_argnums=(0,)),
             **kw,
         )
     else:
         trainer = Trainer(
-            tc, step_fn=jax.jit(model.train_step, donate_argnums=(0,)), **kw
+            tc, step_fn=where.jit(model.train_step, donate_argnums=(0,)), **kw
         )
-    trainer.run()
+    with where.scope:
+        trainer.run()
     h = trainer.history
     # history may be trimmed to a tail (--history-limit): report real steps
     print(f"\narch={args.arch} steps={h[-1]['step'] + 1} "

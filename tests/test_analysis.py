@@ -56,7 +56,7 @@ def test_host_transfer_check_ignores_literal_device_put():
 
 
 def test_f64_check_fires_on_double_cast():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     def bad(x):
         return x.astype(jnp.float64).sum()
@@ -175,7 +175,7 @@ def test_donation_check_passes_when_elided():
 
 
 def test_hlo_f64_check_fires():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     def bad(x):
         return x.astype(jnp.float64) * 2.0

@@ -205,15 +205,15 @@ def test_sharded_fused_plan_bit_identical(shards, rep_k):
 # ---------------------------------------------------------------------------
 
 
-def test_victim_threshold_kernel_matches_ref():
+def test_victim_threshold_kernel_matches_ref(monkeypatch):
+    monkeypatch.setattr(kernel, "THRESHOLD_BLOCK_ROWS", 8)
     rng = np.random.default_rng(7)
     for trial in range(10):
-        c = int(rng.integers(8, 600))
+        c = int(rng.integers(8, 3000))  # up to 3 tiles of 8 x 128 lanes
         kv = int(rng.integers(1, c + 1))
         key = jnp.asarray(rng.integers(-1000, 1000, size=c), jnp.int32)
         u = ref.ordered_u32(key)
-        t, n_gt = kernel.victim_threshold_pallas(u, kv, tile_rows=64,
-                                                 interpret=True)
+        t, n_gt = kernel.victim_threshold_pallas(u, kv, interpret=True)
         srt = jnp.sort(u, descending=True)
         assert jnp.array_equal(t, srt[kv - 1]), trial
         assert int(n_gt) == int(jnp.sum(u > srt[kv - 1])), trial
@@ -231,8 +231,34 @@ def test_bucketize_kernel_matches_ref():
     assert jnp.array_equal(want, got)
 
 
+def test_bucketize_kernel_multi_tile_matches_ref(monkeypatch):
+    monkeypatch.setattr(kernel, "BUCKETIZE_BLOCK_ROWS", 8)
+    # 3000 lanes fold into three 8 x 128 tiles plus padding
+    rng = np.random.default_rng(13)
+    owner = jnp.asarray(rng.integers(-1, 4, size=3000), jnp.int32)
+    local = jnp.asarray(rng.integers(-1, 900, size=3000), jnp.int32)
+    want = ref.bucketize(owner, local, 4)
+    got = kernel.bucketize_pallas(owner, local, 4, interpret=True)
+    assert jnp.array_equal(want, got)
+
+
+def test_f16_bits_decode_exact_for_every_half():
+    """The kernel's float16 -> float32 widening from raw bits agrees with
+    XLA's convert on all 65,536 half patterns (NaNs stay NaN)."""
+    bits = jnp.arange(-(1 << 15), 1 << 15, dtype=jnp.int32).astype(jnp.int16)
+    want = jax.lax.bitcast_convert_type(bits, jnp.float16).astype(jnp.float32)
+    got = jax.jit(kernel._f16_bits_to_f32)(bits.astype(jnp.int32))
+    nan = jnp.isnan(want)
+    assert jnp.array_equal(jnp.isnan(got), nan)
+    wb = jax.lax.bitcast_convert_type(want, jnp.int32)
+    gb = jax.lax.bitcast_convert_type(got, jnp.int32)
+    assert jnp.array_equal(jnp.where(nan, 0, wb), jnp.where(nan, 0, gb))
+
+
 @pytest.mark.parametrize("codec", ["fp16", "int8"])
 def test_gather_decode_kernel_matches_ref(codec):
+    """Under ``GATHER_CHUNK`` < the lane count this exercises the
+    SMEM-bounded device loop (``test_gather_decode_kernel_chunked_matches_ref``)."""
     rng = np.random.default_rng(9)
     ar = ArenaStore.create(
         {"w": jnp.asarray(rng.normal(size=(32, 8)), jnp.float32)}, 8, codec
@@ -249,6 +275,12 @@ def test_gather_decode_kernel_matches_ref(codec):
         )
     )(ar.head["w"], ar.tail["w"], ar.sideband.get("w"), slots)
     assert jnp.array_equal(want, got)
+
+
+@pytest.mark.parametrize("codec", ["fp16", "int8"])
+def test_gather_decode_kernel_chunked_matches_ref(monkeypatch, codec):
+    monkeypatch.setattr(kernel, "GATHER_CHUNK", 4)
+    test_gather_decode_kernel_matches_ref(codec)
 
 
 def test_forced_pallas_route_full_plan():

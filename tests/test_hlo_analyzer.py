@@ -25,10 +25,8 @@ def test_scan_body_multiplied_by_trip_count():
     c = analyze_hlo(comp.as_text())
     expect = 8 * 2 * 128**3
     assert 0.8 * expect < c.flops < 1.3 * expect
-    # and XLA's own analysis indeed counts the body once (the motivation);
-    # cost_analysis() returns a per-device list on newer jax.
+    # and XLA's own analysis indeed counts the body once (the motivation)
     ca = comp.cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
     assert ca["flops"] < 0.3 * expect
 
 
@@ -93,12 +91,10 @@ def test_rolled_while_loop_scatter_stays_touched_rows():
 
 
 def test_cost_analysis_list_return_is_normalized_by_tests():
-    """jax >= 0.4.30 returns cost_analysis() as a per-device list; older
-    versions return a bare dict.  The normalization idiom used across this
-    suite must accept both."""
+    """``cost_analysis()`` returns one dict of XLA's counts (the form
+    ``roofline`` reads under ``xla_raw``)."""
     comp = _compile(lambda a, b: a @ b, jnp.ones((64, 64)), jnp.ones((64, 64)))
     ca = comp.cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
     assert isinstance(ca, dict) and "flops" in ca
     assert ca["flops"] > 0
 

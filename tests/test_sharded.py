@@ -452,3 +452,55 @@ def test_sharded_collection_on_4_device_mesh_matches_reference():
         print("SHARDED_MESH_EXACT")
     """)
     assert "SHARDED_MESH_EXACT" in out
+
+
+@pytest.mark.parametrize("dim", [128, 18, 7])
+def test_uniform_rows_match_the_whole_table_draw(dim):
+    """A shard's rows drawn alone by their threefry counters equal the same
+    rows of the whole-table ``jax.random.uniform`` draw, bit for bit."""
+    from repro.core.sharded import uniform_rows
+
+    key = jax.random.split(jax.random.PRNGKey(3), 2)[1]
+    s = 1.0 / np.sqrt(dim)
+    full = jax.random.uniform(key, (999, dim), jnp.float32, -s, s)
+    rows = jnp.asarray(np.random.default_rng(dim).permutation(999)[:240].reshape(4, 60),
+                       jnp.int32)
+    assert jnp.array_equal(uniform_rows(key, rows, dim, -s, s), full[rows])
+
+
+@pytest.mark.parametrize("with_counts,top_k", [(False, 0), (True, 0), (False, 12),
+                                                (True, 12)])
+def test_sharded_init_draws_rows_like_the_whole_table(monkeypatch, with_counts, top_k):
+    """Init draws each shard's rows (and the replicated head) by counter; the
+    state equals the whole-table draw scattered to the same homes, bit for
+    bit, with frequency counts, a replicated top-K, both or neither."""
+    import repro.core.sharded as sharded
+
+    tables = small_tables()
+    coll = ShardedEmbeddingCollection.create(tables, num_shards=3, cache_ratio=0.2,
+                                             replicate_top_k=top_k)
+    rng = np.random.default_rng(5)
+    counts = ({t.name: rng.integers(0, 50, t.vocab) for t in tables}
+              if with_counts else None)
+    got = coll.init(jax.random.PRNGKey(4), counts=counts)
+    for sname, slab in got.slabs.items():
+        assign = coll.assignments[sname]
+        np.testing.assert_array_equal(np.asarray(slab.rank_owner), assign.owner)
+        np.testing.assert_array_equal(np.asarray(slab.rank_local), assign.local)
+    monkeypatch.setattr(sharded, "_draws_by_counter", lambda dtype: False)
+    want = coll.init(jax.random.PRNGKey(4), counts=counts)
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("dim", [128, 18, 65535])
+def test_row_counters_are_the_64_bit_flat_index(dim):
+    from repro.core.sharded import row_counters
+
+    r = np.array([0, 1, 2**16 - 1, 2**16, 33_762_576, 2**31 - 1], np.int64)
+    hi, lo = row_counters(jnp.asarray(r, jnp.int32), dim)
+    n = r[:, None].astype(np.uint64) * np.uint64(dim) + np.arange(dim, dtype=np.uint64)
+    assert np.array_equal(np.asarray(hi, np.uint64), n >> np.uint64(32))
+    assert np.array_equal(np.asarray(lo, np.uint64), n & np.uint64(0xFFFFFFFF))
