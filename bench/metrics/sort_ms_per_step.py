@@ -1,0 +1,10 @@
+"""Device time of the step's sort-class ops per window step, averaged over
+the cell's chips (profiler trace; ops classed from the compiled step's HLO
+by ``trace.hlo_categories``).  Nothing to read when the class took no time."""
+
+
+def read(run):
+    if not run.devices or not run.window_steps:
+        return None
+    ns = sum(w.by_category["sort"] for w in run.devices.values()) / len(run.devices)
+    return ns / 1e6 / run.window_steps if ns > 0 else None
