@@ -24,7 +24,7 @@ import time
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.core.collection import exact_metric_bytes
+from repro.obs.hub import ExactCounter
 from repro.core.refresh import RefreshConfig
 from repro.launch.mesh import make_hybrid_mesh
 from repro.data import synth
@@ -73,6 +73,16 @@ else:
 
 batches = [{{k: jnp.asarray(v) for k, v in synth.sparse_batch(spec, batch, 0, i).items()}}
            for i in range(6)]
+# exact cumulative exchange bytes per leg (routed lanes x per-lane bytes)
+legs = {{k: ExactCounter() for k in ("exchange_lane_bytes", "exchange_id_lane_bytes",
+                                    "exchange_row_lane_bytes")}}
+
+
+def wire(m):
+    if "exchange_routed_lanes" not in m:
+        return [0, 0, 0]
+    return [c.observe(m["exchange_routed_lanes"], unit=m[k]) for k, c in legs.items()]
+
 moves = 0
 with dist.axis_rules(mesh, rules) if mesh else __import__("contextlib").nullcontext():
     state, m = step(state, batches[0])  # compile + warm
@@ -87,9 +97,7 @@ with dist.axis_rules(mesh, rules) if mesh else __import__("contextlib").nullcont
         # stepping (same re-shard a restart would do)
         state = jax.device_put(dict(state, emb=emb), sh(state_specs))
         moves = sum(report.rebalance_moves.values())
-    x0 = exact_metric_bytes(m, "exchange_routed_lanes", "exchange_lane_bytes") or 0
-    i0 = exact_metric_bytes(m, "exchange_routed_lanes", "exchange_id_lane_bytes") or 0
-    r0 = exact_metric_bytes(m, "exchange_routed_lanes", "exchange_row_lane_bytes") or 0
+    x0, i0, r0 = wire(m)
     h0 = np.asarray(m.get("exchange_per_shard_lanes", np.zeros(1)))
     t0 = time.perf_counter()
     for b in batches[1:]:
@@ -97,9 +105,7 @@ with dist.axis_rules(mesh, rules) if mesh else __import__("contextlib").nullcont
     jax.block_until_ready(m["loss"])
 sec = (time.perf_counter() - t0) / (len(batches) - 1)
 n = len(batches) - 1
-x1 = exact_metric_bytes(m, "exchange_routed_lanes", "exchange_lane_bytes") or 0
-i1 = exact_metric_bytes(m, "exchange_routed_lanes", "exchange_id_lane_bytes") or 0
-r1 = exact_metric_bytes(m, "exchange_routed_lanes", "exchange_row_lane_bytes") or 0
+x1, i1, r1 = wire(m)
 h1 = np.asarray(m.get("exchange_per_shard_lanes", np.zeros(1)))
 hist = ",".join(str(int(v)) for v in (h1 - h0))
 imb = float(m.get("shard_imbalance", 1.0))

@@ -18,7 +18,6 @@ from repro.core.collection import (
     PlacementPlanner,
     ShardAssignment,
     TableConfig,
-    exact_metric_bytes,
 )
 from repro.core.freq import FreqStats, build_freq_stats, collect_counts, coverage
 from repro.core.policies import Policy

@@ -305,6 +305,7 @@ def shard_specs(
             step=P(),
             hits=P(),
             misses=P(),
+            uniq_rows=P(),
             evictions=P(),
             uniq_overflows=P(),
             tier_promotions=P(),

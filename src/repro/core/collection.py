@@ -54,7 +54,6 @@ from repro.core import cache as cache_lib
 from repro.core import freq as freq_lib
 from repro.core import refresh as refresh_lib
 from repro.core.policies import Policy
-from repro.obs.hub import ExactCounter
 from repro.store import (
     ArenaStore,
     HostStore,
@@ -77,8 +76,6 @@ __all__ = [
     "CachedSlab",
     "CollectionState",
     "CollectionPlan",
-    "exact_metric_bytes",
-    "ExactCounterTotals",
 ]
 
 SHARED_ARENA = "__shared__"
@@ -89,7 +86,7 @@ SHARED_ARENA = "__shared__"
 # host-side must leave jit as int32/uint32 — a float cast anywhere in between
 # silently reintroduces the 2^24 resolution drift the pattern exists to kill.
 METRICS_INT_COUNTERS: Tuple[str, ...] = (
-    r"\['slab_(hits|misses|refresh_swaps|refresh_rows"
+    r"\['slab_(hits|misses|uniq_rows|refresh_swaps|refresh_rows"
     r"|tier_promotions|tier_demotions)'\]",
     r"\['host_(moved_rows|row_bytes)'\]",
     r"\['exchange_(routed_lanes|lane_bytes|id_lane_bytes|row_lane_bytes"
@@ -1470,8 +1467,9 @@ class EmbeddingCollection:
         scalar (in-jit convenience — float32 loses integer resolution past
         2^24, so it DRIFTS on long runs), while ``host_moved_rows`` /
         ``host_row_bytes`` are per-slab int32 counters + static encoded row
-        sizes from which :func:`exact_metric_bytes` reconstructs the exact
-        cumulative byte count host-side (what the trainer records)."""
+        sizes from which ``repro.obs.hub.ExactCounter.observe(..., unit=...)``
+        reconstructs the exact cumulative byte count host-side (what the
+        trainer records through its ``MetricsHub``)."""
         hits = misses = evictions = overflows = 0
         win_h = win_m = jnp.zeros((), jnp.float32)
         ref_swaps = ref_rows = jnp.zeros((), jnp.int32)
@@ -1480,6 +1478,7 @@ class EmbeddingCollection:
         row_bytes_map: Dict[str, jnp.ndarray] = {}
         slab_hits: Dict[str, jnp.ndarray] = {}
         slab_misses: Dict[str, jnp.ndarray] = {}
+        slab_uniq_rows: Dict[str, jnp.ndarray] = {}
         slab_ref_swaps: Dict[str, jnp.ndarray] = {}
         slab_ref_rows: Dict[str, jnp.ndarray] = {}
         slab_tier_promotions: Dict[str, jnp.ndarray] = {}
@@ -1492,6 +1491,7 @@ class EmbeddingCollection:
             overflows = overflows + jnp.sum(c.uniq_overflows)
             slab_hits[sname] = jnp.sum(c.hits).astype(jnp.int32)
             slab_misses[sname] = jnp.sum(c.misses).astype(jnp.int32)
+            slab_uniq_rows[sname] = jnp.sum(c.uniq_rows).astype(jnp.int32)
             win_h = win_h + jnp.sum(c.tracker.win_hits)
             win_m = win_m + jnp.sum(c.tracker.win_misses)
             ref_swaps = ref_swaps + jnp.sum(c.tracker.refresh_swaps)
@@ -1536,6 +1536,9 @@ class EmbeddingCollection:
             # above wrap past 2^31 on long runs.
             "slab_hits": slab_hits,
             "slab_misses": slab_misses,
+            # unique rows asked, the unit of ``slab_misses`` (``slab_hits``
+            # counts lanes): 1 - misses / uniq_rows is the row hit rate
+            "slab_uniq_rows": slab_uniq_rows,
             "slab_refresh_swaps": slab_ref_swaps,
             "slab_refresh_rows": slab_ref_rows,
             "slab_tier_promotions": slab_tier_promotions,
@@ -1647,6 +1650,7 @@ class EmbeddingCollection:
                     step=P(),
                     hits=P(),
                     misses=P(),
+                    uniq_rows=P(),
                     evictions=P(),
                     uniq_overflows=P(),
                     tier_promotions=P(),
@@ -1656,35 +1660,3 @@ class EmbeddingCollection:
                 idx_map=P(None),
             )
         return CollectionState(slabs=slabs)
-
-
-def exact_metric_bytes(
-    metrics: Mapping[str, Any], counts_key: str, bytes_key: str
-) -> Optional[int]:
-    """Exact cumulative byte counter from a metrics dict, as a Python int.
-
-    ``metrics[counts_key]`` holds per-slab int32 cumulative counts and
-    ``metrics[bytes_key]`` the matching per-unit byte sizes (both emitted by
-    ``EmbeddingCollection.metrics``); their products are summed in Python
-    integer arithmetic, so — unlike the float32 ``host_wire_bytes`` scalar,
-    which loses integer resolution past 2^24 — the result is exact for the
-    whole int32 range of the counters.  Returns None when the keys are absent
-    (legacy metrics dicts)."""
-    if counts_key not in metrics or bytes_key not in metrics:
-        return None
-    counts = jax.device_get(metrics[counts_key])
-    unit = jax.device_get(metrics[bytes_key])
-    return sum(int(counts[k]) * int(unit[k]) for k in counts)
-
-
-class ExactCounterTotals(ExactCounter):
-    """Back-compat spelling of :class:`repro.obs.hub.ExactCounter`.
-
-    The wrap-safe modulo-2^32 delta accumulation this class introduced (PR5)
-    now lives in the observability hub — ONE implementation shared by the
-    trainer, the serve engine, and the benchmarks instead of a copy per call
-    site.  Kept as an alias so pre-hub callers (``update(per_slab)``)
-    keep working unchanged."""
-
-    def update(self, per_slab: Mapping[str, Any]) -> int:
-        return self.observe(per_slab)

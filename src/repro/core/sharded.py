@@ -90,6 +90,7 @@ from repro.core.collection import (
 )
 from repro.dist import partitioning as dist_part
 from repro.kernels.cache_ops import ops as cache_ops
+from repro.obs.tracing import SCOPE_EXCHANGE
 from repro.store import (
     ArenaStore,
     HostStore,
@@ -1009,7 +1010,9 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
         consumer (:func:`_encoded_exchange`); arena lanes never touch the
         wire, so they are always served raw.  Gradients flow back through
         the same maps, landing on the owning shard's slot / the replicated
-        ``<slab>::rep`` leaf."""
+        ``<slab>::rep`` leaf.  The routed leg runs under the
+        ``shard.exchange`` named scope (its backward keeps the name), so a
+        profiler trace tells the exchange from the rest of the lookup."""
         codec = get_codec(self.exchange_codec) if self.exchange_codec else None
         out = {}
         for f in fb.features:
@@ -1025,13 +1028,14 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
                 ncomb = w.shape[0] * cap
                 rep = weights.get(sname + "::rep")
                 K = rep.shape[0] if rep is not None else 0
-                routed = (flat >= 0) & (flat < ncomb)
-                owner = jnp.where(routed, flat // cap, self.num_shards)
-                slot = jnp.where(routed, flat % cap, 0)
-                if codec is None:
-                    rows = _partitioned_take(w, owner, slot)
-                else:
-                    rows = _encoded_exchange(codec, w, owner, slot)
+                with jax.named_scope(SCOPE_EXCHANGE):
+                    routed = (flat >= 0) & (flat < ncomb)
+                    owner = jnp.where(routed, flat // cap, self.num_shards)
+                    slot = jnp.where(routed, flat % cap, 0)
+                    if codec is None:
+                        rows = _partitioned_take(w, owner, slot)
+                    else:
+                        rows = _encoded_exchange(codec, w, owner, slot)
                 if K:
                     # arena lanes stay shard-local and raw: overlay them on
                     # the routed leg (which returned zero rows for them)
@@ -1308,9 +1312,10 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
         per-slab cumulative id lanes routed through the bucketize exchange
         and the per-lane payload (4 B id out + one fast-tier row back; the
         row leg prices at the exchange codec's encoded width) — exact bytes
-        via ``exact_metric_bytes``.  The two legs are also split out —
-        ``exchange_id_lane_bytes`` / ``exchange_row_lane_bytes`` per slab and
-        ``exchange_id_bytes`` / ``exchange_row_bytes`` float32 totals — and
+        via ``repro.obs.hub.ExactCounter.observe(..., unit=...)``.  The two
+        legs are also split out — ``exchange_id_lane_bytes`` /
+        ``exchange_row_lane_bytes`` per slab and ``exchange_id_bytes`` /
+        ``exchange_row_bytes`` float32 totals — and
         ``exchange_per_shard_lanes`` is the [S] routed-lane histogram summed
         over slabs.  Of the payload an expected (S-1)/S fraction crosses
         devices on an S-shard mesh.
@@ -1482,6 +1487,7 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
                     step=P(axis),
                     hits=P(axis),
                     misses=P(axis),
+                    uniq_rows=P(axis),
                     evictions=P(axis),
                     uniq_overflows=P(axis),
                     tier_promotions=P(axis),

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.analysis.contracts import INT_COUNTERS, contract
 from repro.core import cached_embedding as ce
 from repro.core.collection import EmbeddingCollection, FeatureBatch
+from repro.obs import tracing as scopes
 from repro.optim.optimizers import Optimizer
 
 __all__ = [
@@ -123,6 +124,13 @@ class CollectionTrainStep:
     and cache index state — while step t's dense compute is still running.
     ``__call__`` is exactly their composition, so the serial path stays the
     bit-exactness oracle for the pipelined one.
+
+    Each stage runs under its layer's ``jax.named_scope`` (``cache.plan``,
+    ``cache.movement``, ``cache.lookup``, ``dense``, ``cache.row_update``,
+    ``telemetry``; see ``repro.obs.tracing.STEP_SCOPES``), set here, so the
+    fused and the split stages carry the same names in a profiler trace
+    (the nested ``telemetry`` hit probe and ``shard.exchange`` come from
+    ``core.cache`` and ``core.sharded``).  Scopes are HLO metadata only.
     """
 
     collection: EmbeddingCollection
@@ -143,12 +151,16 @@ class CollectionTrainStep:
         window (their rows are prefetched and pinned; see
         ``EmbeddingCollection.plan_prepare``)."""
         fut = tuple(self.features(b) for b in future_batches)
-        return self.collection.plan_prepare(state["emb"], self.features(batch), fb_future=fut)
+        with jax.named_scope(scopes.SCOPE_PLAN):
+            return self.collection.plan_prepare(
+                state["emb"], self.features(batch), fb_future=fut
+            )
 
     def apply_step(self, state: Dict[str, Any], plan) -> Dict[str, Any]:
         """Execute a plan's row movement (the only prepare half that touches
         weights — run it after the previous step's row update)."""
-        return dict(state, emb=self.collection.apply_plan(state["emb"], plan))
+        with jax.named_scope(scopes.SCOPE_MOVEMENT):
+            return dict(state, emb=self.collection.apply_plan(state["emb"], plan))
 
     # max_sort_size admits the batch-sized ``auc_proxy`` argsort at the
     # analysis.smoke batch of 32, nothing capacity-sized.
@@ -165,24 +177,32 @@ class CollectionTrainStep:
         emb_state = state["emb"]
 
         def loss_fn(dense_params, emb_weights):
-            rows = self.collection.gather(emb_weights, addresses, fb)
-            logits, aux = self.fwd(dense_params, rows, batch)
-            return self.loss(logits, batch["label"]), (logits, aux)
+            with jax.named_scope(scopes.SCOPE_LOOKUP):
+                rows = self.collection.gather(emb_weights, addresses, fb)
+            with jax.named_scope(scopes.SCOPE_DENSE):
+                logits, aux = self.fwd(dense_params, rows, batch)
+                return self.loss(logits, batch["label"]), (logits, aux)
 
+        # one named scope per layer (``repro.obs.tracing.STEP_SCOPES``): the
+        # backward of each scope's ops keeps its name (``transpose(jvp(..))``)
         (loss_val, (logits, aux)), grads = jax.value_and_grad(
             loss_fn, argnums=(0, 1), has_aux=True
         )(state["params"], self.collection.weights(emb_state))
-        params, opt_state = self.optimizer.update(
-            grads[0], state["opt"], state["params"], state["step"]
-        )
-        emb_state = self.collection.apply_grads(emb_state, grads[1], self.emb_lr)
-        metrics = {
-            "loss": loss_val,
-            "auc": auc_proxy(logits, batch["label"]),
-            **self.collection.metrics(emb_state),
-            **aux,
-        }
-        new_state = dict(state, params=params, opt=opt_state, emb=emb_state, step=state["step"] + 1)
+        with jax.named_scope(scopes.SCOPE_DENSE):
+            params, opt_state = self.optimizer.update(
+                grads[0], state["opt"], state["params"], state["step"]
+            )
+            step = state["step"] + 1
+        with jax.named_scope(scopes.SCOPE_ROW_UPDATE):
+            emb_state = self.collection.apply_grads(emb_state, grads[1], self.emb_lr)
+        with jax.named_scope(scopes.SCOPE_TELEMETRY):
+            metrics = {
+                "loss": loss_val,
+                "auc": auc_proxy(logits, batch["label"]),
+                **self.collection.metrics(emb_state),
+                **aux,
+            }
+        new_state = dict(state, params=params, opt=opt_state, emb=emb_state, step=step)
         return new_state, metrics
 
     def __call__(self, state: Dict[str, Any], batch: Dict[str, jnp.ndarray]):

@@ -9,7 +9,7 @@ percentiles).  ``python -m repro.obs.report <run.jsonl>`` renders a run.
 """
 from repro.obs.hist import FixedHistogram, log_bounds
 from repro.obs.hub import ExactCounter, Gauge, MetricsHub
-from repro.obs.tracing import NULL_TRACER, Tracer
+from repro.obs.tracing import NULL_TRACER, STEP_SCOPES, Tracer
 
 __all__ = [
     "ExactCounter",
@@ -17,6 +17,7 @@ __all__ = [
     "Gauge",
     "MetricsHub",
     "NULL_TRACER",
+    "STEP_SCOPES",
     "Tracer",
     "log_bounds",
 ]

@@ -4,10 +4,9 @@ Every int32 counter the cache threads through jit — hits, misses, routed
 exchange lanes, host rows moved, refresh swaps — is CUMULATIVE device state
 that (a) wraps past 2^31 on long runs (x64 is off) and (b) only becomes a
 trustworthy Python int through modulo-2^32 delta accumulation host-side.
-Before this module that wrap-safe pattern lived in three places
-(``Trainer._post_step``, ``ServeEngine.summary``, and ad-hoc
-``exact_metric_bytes`` call sites in the benchmarks); :class:`ExactCounter`
-is the one implementation, and :meth:`MetricsHub.observe_embedding_metrics`
+:class:`ExactCounter` is the one implementation of that wrap-safe pattern
+(the trainer, the serve engine and the benchmarks all use it), and
+:meth:`MetricsHub.observe_embedding_metrics`
 is the ONE place that knows which families a ``collection.metrics`` dict
 carries and how each reconstructs (per-slab counts, optionally priced by a
 static per-unit byte size).
@@ -61,9 +60,8 @@ class ExactCounter:
       observations, which one step can never exceed — and summed in Python
       integers.  With ``unit`` (an int, or a per-key mapping of ints), each
       key's delta is multiplied by its unit BEFORE summing, so byte totals
-      (rows x encoded row size) are wrap-safe too — unlike the legacy
-      ``exact_metric_bytes`` one-shot product, which inherits the int32 wrap
-      of the count it reads.
+      (rows x encoded row size) are wrap-safe too — unlike a one-shot
+      product of the raw counter, which inherits its int32 wrap.
 
     Idempotent under repeated observation of the same values (delta 0), so
     summaries may call it freely.  Totals count from the first observation's
@@ -128,6 +126,7 @@ class Gauge:
 _CUMULATIVE_FAMILIES = (
     ("cache_hits", "slab_hits", None),
     ("cache_misses", "slab_misses", None),
+    ("cache_uniq_rows", "slab_uniq_rows", None),
     ("host_moved_rows", "host_moved_rows", None),
     ("host_wire_bytes", "host_moved_rows", "host_row_bytes"),
     ("exchange_routed_lanes", "exchange_routed_lanes", None),
@@ -203,10 +202,8 @@ class MetricsHub:
         """Feed one observation of a ``collection.metrics`` dict; returns the
         exact-int record for the families present (wrap-safe Python ints).
 
-        This replaces the per-call-site ``ExactCounterTotals`` pairs and
-        ``exact_metric_bytes`` calls the trainer, the serve engine, and the
-        benchmarks each hand-rolled: add a counter family to
-        ``_CUMULATIVE_FAMILIES`` and every consumer reports it.  Derived
+        Add a counter family to ``_CUMULATIVE_FAMILIES`` and every
+        consumer (trainer, serve engine, benchmarks) reports it.  Derived
         ``hit_rate_exact`` rides along whenever both hit families exist.
 
         One ``jax.device_get`` for the whole observation: the per-slab

@@ -12,10 +12,13 @@ group structure visible.
 Two caveats, by design:
 
 * JAX dispatch is asynchronous — a span around ``compute_fn(...)`` measures
-  *dispatch* time unless something blocks inside it.  The blocking point is
-  explicit in the trainer (the once-per-step loss fetch is its own
-  ``host-transfer`` span), so the span profile shows where the Python loop
-  spends wall-clock, which is exactly the quantity the pipeline overlaps.
+  *dispatch* time unless something blocks inside it.  The blocking points
+  are explicit in the trainer (the per-step loss fetch is the
+  ``host-transfer`` span; the overflow guard, float telemetry and hub
+  counter fetches after it are ``host-transfer.overflow`` /
+  ``.telemetry`` / ``.counters``), so the span profile shows where the
+  Python loop spends wall-clock, which is exactly the quantity the pipeline
+  overlaps.
 * device-side timing needs the real profiler: with ``annotate=True`` every
   span also enters a ``jax.profiler.TraceAnnotation``, so the same stage
   names appear on the device timeline when a ``jax.profiler.trace`` capture
@@ -34,7 +37,24 @@ import threading
 import time
 from typing import Any, Dict, List
 
-__all__ = ["Tracer", "NULL_TRACER"]
+__all__ = ["Tracer", "NULL_TRACER", "STEP_SCOPES"]
+
+# The compiled step's layers, as ``jax.named_scope`` names.  Each stage of
+# ``models.common.CollectionTrainStep`` runs under one, so every op of the
+# jitted step carries its layer in the HLO ``op_name`` metadata and a
+# profiler trace splits device time by layer.  Two nest inside others:
+# ``telemetry`` also names the plan's id-level hit probe
+# (``core.cache.plan_prepare``), ``shard.exchange`` the sharded lookup's
+# cross-shard leg (``core.sharded``).
+SCOPE_PLAN = "cache.plan"
+SCOPE_MOVEMENT = "cache.movement"
+SCOPE_LOOKUP = "cache.lookup"
+SCOPE_EXCHANGE = "shard.exchange"
+SCOPE_DENSE = "dense"
+SCOPE_ROW_UPDATE = "cache.row_update"
+SCOPE_TELEMETRY = "telemetry"
+STEP_SCOPES = (SCOPE_PLAN, SCOPE_MOVEMENT, SCOPE_LOOKUP, SCOPE_EXCHANGE,
+               SCOPE_DENSE, SCOPE_ROW_UPDATE, SCOPE_TELEMETRY)
 
 
 class Tracer:

@@ -161,16 +161,18 @@ class Trainer:
         overflow monitors and the checkpoint cadence; returns the (possibly
         flushed) state."""
         cfg = self.cfg
-        # block on one scalar so step time is real, rest stays async; this
-        # fetch is the step's ONE deliberate device->host sync point (its own
-        # span so the wall-clock profile shows where the loop blocks)
+        # block on one scalar so step time is real, rest stays async.  Up to
+        # three more fetches follow (overflow guard, float telemetry, hub
+        # counters); each has its own ``host-transfer*`` span, so a profile
+        # shows which one the loop blocks on and the device idles under.
         with self.tracer.span("host-transfer"):
             loss = float(jax.device_get(metrics["loss"]))
         dt = time.perf_counter() - t0
         if self.detector.observe(dt) and self.on_straggler:
             self.on_straggler(step_i, dt)
         if cfg.assert_no_uniq_overflow and "uniq_overflows" in metrics:
-            n_over = int(jax.device_get(metrics["uniq_overflows"]))
+            with self.tracer.span("host-transfer.overflow"):
+                n_over = int(jax.device_get(metrics["uniq_overflows"]))
             if n_over:
                 raise RuntimeError(
                     f"cache unique-buffer overflow at step {step_i}: raise "
@@ -186,7 +188,8 @@ class Trainer:
             if k in metrics
         ]
         if float_keys:  # one fetch for all float telemetry, not one per key
-            fetched = jax.device_get({k: metrics[k] for k in float_keys})
+            with self.tracer.span("host-transfer.telemetry"):
+                fetched = jax.device_get({k: metrics[k] for k in float_keys})
             rec.update({k: float(v) for k, v in fetched.items()})
         # every cumulative int32 counter family in the metrics dict —
         # hits/misses, host rows and ENCODED wire bytes, exchange lanes and
@@ -196,10 +199,13 @@ class Trainer:
         # are present).  A float32 accumulator loses integer resolution past
         # 2^24 and the in-jit int32 counters wrap past 2^31; neither survives
         # a long run, which is why everything routes through the hub.
-        rec.update(self.hub.observe_embedding_metrics(metrics))
-        if "host_wire_bytes" not in rec and "host_wire_bytes" in metrics:
-            # legacy metrics dicts carry only the float32 scalar fallback
-            rec["host_wire_bytes"] = float(jax.device_get(metrics["host_wire_bytes"]))
+        with self.tracer.span("host-transfer.counters"):
+            rec.update(self.hub.observe_embedding_metrics(metrics))
+            if "host_wire_bytes" not in rec and "host_wire_bytes" in metrics:
+                # legacy metrics dicts carry only the float32 scalar fallback
+                rec["host_wire_bytes"] = float(
+                    jax.device_get(metrics["host_wire_bytes"])
+                )
         self.hub.histogram("step_time_s").observe(dt)
         self.hub.log(
             "step",

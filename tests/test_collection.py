@@ -330,7 +330,10 @@ def test_host_wire_bytes_exact_past_float32_resolution():
             slab.cache, misses=jnp.asarray(moved, jnp.int32)))})
     m = coll.metrics(state)
     expect = moved * 32
-    assert col.exact_metric_bytes(m, "host_moved_rows", "host_row_bytes") == expect
+    from repro.obs.hub import ExactCounter
+
+    got = ExactCounter().observe(m["host_moved_rows"], unit=m["host_row_bytes"])
+    assert got == expect and isinstance(got, int)
     # ...and the in-jit float32 convenience scalar demonstrably drifts
     assert int(m["host_wire_bytes"]) != expect
 

@@ -44,8 +44,8 @@ def test_exact_counter_per_slab_and_idempotent():
 
 
 def test_exact_counter_unit_weighted_bytes_are_wrap_safe():
-    # bytes = rows x static row size must survive the ROW counter wrapping —
-    # the legacy one-shot product (exact_metric_bytes) inherits the wrap.
+    # bytes = rows x static row size must survive the ROW counter wrapping,
+    # which a one-shot product of the raw counter would inherit.
     c = ExactCounter()
     c.observe({"s": _wrapped(2**31 - 10)}, unit={"s": jnp.asarray(128)})
     c.observe({"s": _wrapped(2**31 + 10)}, unit={"s": jnp.asarray(128)})
@@ -57,6 +57,7 @@ def test_exact_counter_unit_weighted_bytes_are_wrap_safe():
     [
         ("slab_hits", None, "cache_hits"),
         ("slab_misses", None, "cache_misses"),
+        ("slab_uniq_rows", None, "cache_uniq_rows"),
         ("host_moved_rows", "host_row_bytes", "host_wire_bytes"),
         ("exchange_routed_lanes", None, "exchange_routed_lanes"),
         ("exchange_routed_lanes", "exchange_lane_bytes", "exchange_bytes"),

@@ -478,7 +478,9 @@ def test_collect_counts_stream_matches_materialized_counts():
 def test_exact_counter_totals_survive_int32_wrap():
     """The satellite bugfix: cumulative int32 hit counters wrap past 2^31;
     the host-side accumulator recovers exact Python-int totals."""
-    ec = col.ExactCounterTotals()
+    from repro.obs.hub import ExactCounter
+
+    ec = ExactCounter()
     step = 1 << 28  # 268M events per observation
     seen = 0
     cur = np.int32(0)
@@ -486,11 +488,11 @@ def test_exact_counter_totals_survive_int32_wrap():
         with np.errstate(over="ignore"):
             cur = np.int32(cur + np.int32(step))
         seen += step
-        got = ec.update({"slab": cur})
+        got = ec.observe({"slab": cur})
     assert got == seen  # 5.3B events, far past int32
     assert int(cur) != seen  # the raw counter really did wrap
     # idempotent re-observation
-    assert ec.update({"slab": cur}) == seen
+    assert ec.observe({"slab": cur}) == seen
 
 
 def test_trainer_records_exact_hit_totals():

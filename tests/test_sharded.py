@@ -360,9 +360,9 @@ def test_exchange_telemetry_counts_valid_lanes():
     per_lane = int(m["exchange_lane_bytes"][col.SHARED_ARENA])
     assert per_lane == 4 + 8 * 4  # id out + one dim-8 fp32 row back
     assert float(m["exchange_bytes"]) == lanes * per_lane
-    from repro.core.collection import exact_metric_bytes
-    assert exact_metric_bytes(m, "exchange_routed_lanes",
-                              "exchange_lane_bytes") == lanes * per_lane
+    from repro.obs.hub import ExactCounter
+    assert ExactCounter().observe(m["exchange_routed_lanes"],
+                                  unit=m["exchange_lane_bytes"]) == lanes * per_lane
 
 
 def test_device_budget_mode_composes_with_sharding():
