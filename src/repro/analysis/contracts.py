@@ -27,7 +27,7 @@ __all__ = ["Contract", "Violation", "contract", "registry", "INT_COUNTERS"]
 # threads through jit stays int32/uint32 — matched against output tree paths
 # (``jax.tree_util.keystr``; registered-dataclass fields render as ``.name``).
 INT_COUNTERS: Tuple[str, ...] = (
-    r"\.(step|hits|misses|uniq_rows|evictions|uniq_overflows|last_used|use_count"
+    r"\.(step|hits|misses|uniq_rows|move_rounds|evictions|uniq_overflows|last_used|use_count"
     r"|slot_to_row|row_to_slot|last_touch|refresh_swaps|refresh_rows"
     r"|routed_lanes|tier_promotions|tier_demotions)$",
 )

@@ -12,9 +12,11 @@ State layout (all device-resident, mirroring the paper's "green boxes"):
   counters      hit/miss/transfer telemetry (int64 scalars)
 
 Shapes are static: each ``prepare`` call ingests a fixed-size padded id vector,
-takes a fixed-size ``unique``, and drives the bounded-buffer transmitter for a
-fixed number of rounds — the compile-time promotion of the paper's "strictly
-limit the buffer size / complete the transfer multiple times".
+takes a fixed-size ``unique``, and drives the bounded-buffer transmitter, whose
+``buffer_rows`` bounds each round's staging block — the compile-time promotion
+of the paper's "strictly limit the buffer size / complete the transfer multiple
+times".  The plan compacts its loads to the front lanes, so the transmitter
+runs only the rounds up to the plan's last active lane (``move_rounds``).
 
 Invariant (tested property): after ``prepare``, every id of the batch maps to
 a resident slot, and lookups through the cache are bit-identical to lookups
@@ -164,6 +166,11 @@ class CacheState:
     # the plan's gather from ``tracker.last_touch`` ran 2 ms (28%) slower a
     # step on a TPU v5e (Criteo one-chip share, same HLO)
     uniq_rows: jnp.ndarray
+    # int32 [] transmitter rounds that reach the plans' loads, ceil(loads /
+    # buffer_rows) a plan: what the load loop runs (the write-back runs as
+    # many; a single-round move runs its round anyway).  After ``uniq_rows``
+    # for the same reason
+    move_rounds: jnp.ndarray
 
     def hit_rate(self) -> jnp.ndarray:
         tot = self.hits + self.misses
@@ -195,6 +202,7 @@ def init_cache(cfg: CacheConfig, row_tree_example: Any) -> CacheState:
         hits=jnp.zeros((), jnp.int32),
         misses=jnp.zeros((), jnp.int32),
         uniq_rows=jnp.zeros((), jnp.int32),
+        move_rounds=jnp.zeros((), jnp.int32),
         evictions=jnp.zeros((), jnp.int32),
         uniq_overflows=jnp.zeros((), jnp.int32),
         tier_promotions=jnp.zeros((), jnp.int32),
@@ -521,12 +529,25 @@ def plan_prepare(
 
 @contract(donates=("full_rows", "state"), int_counters=INT_COUNTERS, max_sort_size=0)
 def apply_plan(
-    cfg: CacheConfig, full_rows: Any, state: CacheState, plan: CachePlan
+    cfg: CacheConfig,
+    full_rows: Any,
+    state: CacheState,
+    plan: CachePlan,
+    live_lanes: Optional[jnp.ndarray] = None,
 ) -> Tuple[Any, CacheState]:
     """Execute a ``CachePlan``: write back displaced rows, load missed rows,
     install the index image.  The only half that touches weights — in the
     pipelined trainer it runs after the previous step's row update so evicted
-    rows carry their freshest values."""
+    rows carry their freshest values.
+
+    The plan's active lanes are its first ``sum(load_active)`` (loads are
+    compacted to the front, ``evict_active`` is a subset), so both
+    transmitter moves run only the rounds that reach them.  ``live_lanes``
+    overrides that count with any bound at or above it — the sharded
+    collection passes one count for all shards, from outside its ``vmap``,
+    so the loop's trip count is not batched."""
+    if live_lanes is None:
+        live_lanes = jnp.sum(plan.load_active, dtype=jnp.int32)
     # chunk granularity applies to the SLOW-tier side only (the full table):
     # writebacks scatter into it, loads gather from it.  The cache side stays
     # row-granular — its slots are a permutation with no useful locality.
@@ -539,6 +560,7 @@ def apply_plan(
             plan.evict_active,
             buffer_rows=cfg.buffer_rows,
             dst_chunk_rows=cfg.chunk_rows,
+            live_lanes=live_lanes,
         )
     cached_rows = transmitter.move_rows(
         full_rows,
@@ -548,6 +570,10 @@ def apply_plan(
         plan.load_active,
         buffer_rows=cfg.buffer_rows,
         src_chunk_rows=cfg.chunk_rows,
+        live_lanes=live_lanes,
+    )
+    rounds = transmitter.live_rounds(
+        plan.miss_rows.shape[0], cfg.buffer_rows, live_lanes
     )
     new_state = CacheState(
         cached_rows=cached_rows,
@@ -559,6 +585,7 @@ def apply_plan(
         hits=plan.hits,
         misses=plan.misses,
         uniq_rows=plan.uniq_rows,
+        move_rounds=state.move_rounds + rounds,
         evictions=plan.evictions,
         uniq_overflows=plan.uniq_overflows,
         tier_promotions=plan.tier_promotions,

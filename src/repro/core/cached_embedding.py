@@ -306,6 +306,7 @@ def shard_specs(
             hits=P(),
             misses=P(),
             uniq_rows=P(),
+            move_rounds=P(),
             evictions=P(),
             uniq_overflows=P(),
             tier_promotions=P(),

@@ -86,7 +86,7 @@ SHARED_ARENA = "__shared__"
 # host-side must leave jit as int32/uint32 — a float cast anywhere in between
 # silently reintroduces the 2^24 resolution drift the pattern exists to kill.
 METRICS_INT_COUNTERS: Tuple[str, ...] = (
-    r"\['slab_(hits|misses|uniq_rows|refresh_swaps|refresh_rows"
+    r"\['slab_(hits|misses|uniq_rows|move_rounds|refresh_swaps|refresh_rows"
     r"|tier_promotions|tier_demotions)'\]",
     r"\['host_(moved_rows|row_bytes)'\]",
     r"\['exchange_(routed_lanes|lane_bytes|id_lane_bytes|row_lane_bytes"
@@ -1479,6 +1479,7 @@ class EmbeddingCollection:
         slab_hits: Dict[str, jnp.ndarray] = {}
         slab_misses: Dict[str, jnp.ndarray] = {}
         slab_uniq_rows: Dict[str, jnp.ndarray] = {}
+        slab_move_rounds: Dict[str, jnp.ndarray] = {}
         slab_ref_swaps: Dict[str, jnp.ndarray] = {}
         slab_ref_rows: Dict[str, jnp.ndarray] = {}
         slab_tier_promotions: Dict[str, jnp.ndarray] = {}
@@ -1492,6 +1493,8 @@ class EmbeddingCollection:
             slab_hits[sname] = jnp.sum(c.hits).astype(jnp.int32)
             slab_misses[sname] = jnp.sum(c.misses).astype(jnp.int32)
             slab_uniq_rows[sname] = jnp.sum(c.uniq_rows).astype(jnp.int32)
+            # the shards of a sharded slab share one loop: max, not sum
+            slab_move_rounds[sname] = jnp.max(c.move_rounds).astype(jnp.int32)
             win_h = win_h + jnp.sum(c.tracker.win_hits)
             win_m = win_m + jnp.sum(c.tracker.win_misses)
             ref_swaps = ref_swaps + jnp.sum(c.tracker.refresh_swaps)
@@ -1539,6 +1542,8 @@ class EmbeddingCollection:
             # unique rows asked, the unit of ``slab_misses`` (``slab_hits``
             # counts lanes): 1 - misses / uniq_rows is the row hit rate
             "slab_uniq_rows": slab_uniq_rows,
+            # transmitter rounds the loads ran (row movement's loop trips)
+            "slab_move_rounds": slab_move_rounds,
             "slab_refresh_swaps": slab_ref_swaps,
             "slab_refresh_rows": slab_ref_rows,
             "slab_tier_promotions": slab_tier_promotions,
@@ -1651,6 +1656,7 @@ class EmbeddingCollection:
                     hits=P(),
                     misses=P(),
                     uniq_rows=P(),
+                    move_rounds=P(),
                     evictions=P(),
                     uniq_overflows=P(),
                     tier_promotions=P(),

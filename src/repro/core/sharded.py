@@ -937,15 +937,22 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
     ) -> CollectionState:
         """Execute every shard's planned row movement (vmapped: each shard
         moves rows between ITS host-store slice and ITS cache arena — no
-        cross-shard traffic) and accumulate the exchange telemetry."""
+        cross-shard traffic) and accumulate the exchange telemetry.
+
+        All shards run the transmitter rounds that reach the busiest shard's
+        loads.  That count is taken outside the ``vmap``: a batched trip
+        count would lower the loop with a batched predicate, a ``select``
+        over the whole carried slow tier in every round."""
         slabs = dict(state.slabs)
         for sname, p in plan.slab_plans.items():
             spec = self.cached_slabs[sname]
             ccfg = self.shard_cache_config(spec, writeback=plan.writeback)
             slab = slabs[sname]
+            live = jnp.max(jnp.sum(p.load_active, axis=-1, dtype=jnp.int32))
             full, cache = jax.vmap(
-                lambda f, c, pp: cache_lib.apply_plan(ccfg, f, c, pp)
-            )(slab.full, slab.cache, p)
+                lambda f, c, pp, n: cache_lib.apply_plan(ccfg, f, c, pp, live_lanes=n),
+                in_axes=(0, 0, 0, None),
+            )(slab.full, slab.cache, p, live)
             rep = slab.rep
             step = rep.step + 1  # ticks with the per-shard plan clocks
             u = plan.uniq_ranks.get(sname)
@@ -1488,6 +1495,7 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
                     hits=P(axis),
                     misses=P(axis),
                     uniq_rows=P(axis),
+                    move_rounds=P(axis),
                     evictions=P(axis),
                     uniq_overflows=P(axis),
                     tier_promotions=P(axis),

@@ -6,9 +6,14 @@ TPU host), and scatters on the target — with a strictly limited buffer, so a
 big transfer completes in multiple rounds.
 
 JAX/XLA adaptation: shapes must be static, so the transmitter has a fixed
-per-round budget ``buffer_rows`` and always executes ``ceil(K / buffer_rows)``
-rounds over the (padded) index arrays.  Inactive lanes use out-of-bounds
-indices with ``mode='drop'`` / ``mode='fill'`` so they are hardware no-ops.
+per-round budget ``buffer_rows`` and lays the (padded) index arrays out as
+``ceil(K / buffer_rows)`` rounds.  Inactive lanes use out-of-bounds indices
+with ``mode='drop'`` / ``mode='fill'`` so they are no-ops, but a round costs
+its ``buffer_rows`` lanes whether they carry rows or not.  A caller whose
+active lanes sit at the front (a cache plan compacts its loads there) passes
+``live_lanes``, the count of leading lanes that may be active, and the loop
+runs only the ``ceil(live_lanes / buffer_rows)`` rounds that reach them: the
+trip count is traced, the staging block keeps its static size.
 The pack -> move -> scatter structure is kept explicit (``pack`` is a gather
 into a contiguous [buffer_rows, ...] staging block — exactly the paper's
 buffer) so that on TPU the staging block is what crosses the host/device
@@ -43,11 +48,21 @@ import jax.numpy as jnp
 from repro.store.arena import ArenaStore
 from repro.store.host_store import HostStore
 
-__all__ = ["move_rows", "write_rows", "gather_rows", "scatter_rows", "num_rounds"]
+__all__ = ["move_rows", "write_rows", "gather_rows", "scatter_rows", "num_rounds",
+           "live_rounds"]
 
 
 def num_rounds(k: int, buffer_rows: int) -> int:
     return -(-k // buffer_rows)
+
+
+def live_rounds(k: int, buffer_rows: int, live_lanes: jnp.ndarray) -> jnp.ndarray:
+    """Rounds of a ``K = k``-lane move that reach its first ``live_lanes``
+    lanes: ``ceil(live_lanes / buffer_rows)``, at most the static round count
+    (int32 scalar, traced)."""
+    buffer_rows = min(buffer_rows, k)
+    live = jnp.asarray(live_lanes, jnp.int32)
+    return jnp.minimum(-(-live // buffer_rows), num_rounds(k, buffer_rows))
 
 
 # ---------------------------------------------------------------------------
@@ -223,13 +238,19 @@ def move_rows(
     buffer_rows: int,
     src_chunk_rows: int = 0,
     dst_chunk_rows: int = 0,
+    live_lanes: Optional[jnp.ndarray] = None,
 ) -> Any:
     """Move rows ``src_idx`` of ``src_tree`` to positions ``dst_idx`` of ``dst_tree``.
 
-    ``active`` masks real lanes; all arrays have static length K.  The move is
-    performed in ``ceil(K/buffer_rows)`` rounds through a [buffer_rows, ...]
-    staging block.  Returns the updated ``dst_tree``.  Designed to be called
+    ``active`` masks real lanes; all arrays have static length K, laid out as
+    ``ceil(K/buffer_rows)`` rounds through a [buffer_rows, ...] staging
+    block.  Returns the updated ``dst_tree``.  Designed to be called
     from inside a jitted step (it is pure; no own jit so the caller fuses it).
+
+    ``live_lanes`` (a scalar, may be traced) promises that every lane at or
+    past it is inactive; the loop then stops after the rounds that reach it
+    (:func:`live_rounds`), which changes no output.  ``None`` runs every
+    round.  A single-round move has no loop and ignores it.
 
     Either side may be a ``HostStore``: loads gather the encoded staging
     block and decode it at the device end; writebacks encode the block
@@ -317,6 +338,8 @@ def move_rows(
 
     if rounds == 1:
         return body(0, dst_tree)
+    if live_lanes is not None:
+        rounds = live_rounds(k, buffer_rows, live_lanes)  # traced: a `while`
     return jax.lax.fori_loop(0, rounds, body, dst_tree)
 
 

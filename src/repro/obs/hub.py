@@ -127,6 +127,7 @@ _CUMULATIVE_FAMILIES = (
     ("cache_hits", "slab_hits", None),
     ("cache_misses", "slab_misses", None),
     ("cache_uniq_rows", "slab_uniq_rows", None),
+    ("cache_move_rounds", "slab_move_rounds", None),
     ("host_moved_rows", "host_moved_rows", None),
     ("host_wire_bytes", "host_moved_rows", "host_row_bytes"),
     ("exchange_routed_lanes", "exchange_routed_lanes", None),

@@ -232,3 +232,66 @@ def test_uvm_row_key_is_recency_not_frequency():
         cache_lib.eviction_key(Policy.UVM_ROW, slot_to_row, last_used, use_count)
     )
     assert key[0] > key[1]  # stale slot carries the larger (evict-first) key
+
+
+# --------------------------------------------------------------------------
+# apply_plan runs only the transmitter rounds that reach the plan's loads
+# --------------------------------------------------------------------------
+
+
+def _static_apply(cfg, full, state, plan):
+    """``apply_plan`` as it ran every round: both moves at the static round
+    count, the plan's index image installed."""
+    from repro.core import transmitter
+
+    if cfg.writeback:
+        full = transmitter.move_rows(
+            state.cached_rows, full, plan.victim_slots, plan.victim_rows,
+            plan.evict_active, buffer_rows=cfg.buffer_rows, dst_chunk_rows=cfg.chunk_rows)
+    cached = transmitter.move_rows(
+        full, state.cached_rows, plan.miss_rows, plan.victim_slots, plan.load_active,
+        buffer_rows=cfg.buffer_rows, src_chunk_rows=cfg.chunk_rows)
+    return full, cached
+
+
+@pytest.mark.parametrize("writeback", [True, False])
+@pytest.mark.parametrize("lookahead", [False, True])
+@pytest.mark.parametrize("use_pallas_plan", [False, True])
+def test_apply_plan_live_rounds_bit_identical_to_static_rounds(
+    use_pallas_plan, lookahead, writeback
+):
+    """Over a stream whose miss counts span zero to several rounds, each
+    ``apply_plan`` gives the arena, slow tier and index image the static
+    round count gives, and ``move_rounds`` grows by ceil(loads / buffer)."""
+    cfg = cache_lib.CacheConfig(vocab=80, capacity=24, ids_per_step=16, buffer_rows=3,
+                                use_pallas_plan=use_pallas_plan, writeback=writeback)
+    rng = np.random.default_rng(5)
+    full = {"weight": jnp.asarray(rng.normal(size=(80, 8)), jnp.float32)}
+    state = cache_lib.init_cache(cfg, {"weight": jnp.zeros((8,), jnp.float32)})
+    plan_j = jax.jit(lambda s, r, f: cache_lib.plan_prepare(
+        cfg, s, r, future_rows=f if lookahead else None))
+    apply_j = jax.jit(lambda f, s, p: cache_lib.apply_plan(cfg, f, s, p))
+    batches = [rng.integers(-1, 80, 16) for _ in range(3)]
+    batches += [batches[-1], np.full(16, -1), rng.integers(0, 6, 16), rng.integers(-1, 80, 16)]
+    rounds, seen = 0, set()
+    for i, rows in enumerate(batches):
+        fut = jnp.asarray(batches[(i + 1) % len(batches)][:8], jnp.int32)
+        plan = plan_j(state, jnp.asarray(rows, jnp.int32), fut)
+        loads = int(jnp.sum(plan.load_active))
+        act = np.asarray(plan.load_active)
+        assert not act[loads:].any()  # the loads sit at the front
+        assert not (np.asarray(plan.evict_active) & ~act).any()
+        want_full, want_rows = _static_apply(cfg, full, state, plan)
+        full, new = apply_j(full, state, plan)
+        for a, b in zip(jax.tree_util.tree_leaves((want_full, want_rows)),
+                        jax.tree_util.tree_leaves((full, new.cached_rows))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for name in ("slot_to_row", "row_to_slot", "last_used", "use_count", "step",
+                     "hits", "misses", "uniq_rows", "evictions"):
+            np.testing.assert_array_equal(np.asarray(getattr(new, name)),
+                                          np.asarray(getattr(plan, name)))
+        rounds += -(-loads // cfg.buffer_rows)
+        assert int(new.move_rounds) == rounds
+        seen.add(-(-loads // cfg.buffer_rows))
+        state = new
+    assert 0 in seen and max(seen) >= 3, seen  # empty plans and multi-round ones

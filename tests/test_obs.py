@@ -58,6 +58,7 @@ def test_exact_counter_unit_weighted_bytes_are_wrap_safe():
         ("slab_hits", None, "cache_hits"),
         ("slab_misses", None, "cache_misses"),
         ("slab_uniq_rows", None, "cache_uniq_rows"),
+        ("slab_move_rounds", None, "cache_move_rounds"),
         ("host_moved_rows", "host_row_bytes", "host_wire_bytes"),
         ("exchange_routed_lanes", None, "exchange_routed_lanes"),
         ("exchange_routed_lanes", "exchange_lane_bytes", "exchange_bytes"),

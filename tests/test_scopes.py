@@ -196,6 +196,50 @@ def test_uniq_rows_on_4_shards_counts_unique_rows_of_each_shard_plan():
     assert "OK 4" in out
 
 
+def check_move_rounds(model, state, step, make, steps: int, buffer_rows: int):
+    """Each step's ``cache_move_rounds`` delta is ceil(loads / buffer_rows),
+    with the loads of the busiest shard (one chip: of the one plan): the
+    serial step loads exactly its misses."""
+    hub = MetricsHub()
+    want, seen = 0, set()
+    for s in range(steps):
+        before = np.asarray(state["emb"].slabs["__shared__"].cache.misses)
+        state, m = step(state, make(s // 2))  # each batch twice: no loads
+        loads = np.max(np.asarray(state["emb"].slabs["__shared__"].cache.misses) - before)
+        r = -(-int(loads) // buffer_rows)
+        want += r
+        seen.add(r)
+        assert hub.observe_embedding_metrics(m)["cache_move_rounds"] == want, (s, r)
+    assert min(seen) == 0 and max(seen) > 1, seen
+    return want
+
+
+def test_move_rounds_counts_the_rounds_that_reach_the_loads():
+    cfg = DLRMConfig(**BASE, buffer_rows=8)
+    model = DLRM(cfg)
+    state = model.init(jax.random.PRNGKey(0))
+    check_move_rounds(model, state, jax.jit(model.train_step), _make(cfg), 6, 8)
+
+
+def test_move_rounds_on_4_shards_counts_the_busiest_shards_rounds():
+    out = run_sub("""
+        import jax
+        from repro.launch.train import place
+        from repro.models.dlrm import DLRM, DLRMConfig
+        import test_scopes as ts
+
+        cfg = DLRMConfig(**ts.BASE, model_shards=4, buffer_rows=4)
+        model = DLRM(cfg)
+        where = place(model, ts._make(cfg), 4)
+        with where.scope:
+            state = where.init_fn()
+            step = where.jit(model.train_step)
+            rounds = ts.check_move_rounds(model, state, step, where.make_batch, 6, 4)
+        print("OK", len(jax.devices()), rounds)
+    """)
+    assert "OK 4" in out
+
+
 # --------------------------------------------------------------------------
 # the trainer's post-step fetch spans
 # --------------------------------------------------------------------------

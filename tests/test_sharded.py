@@ -365,6 +365,52 @@ def test_exchange_telemetry_counts_valid_lanes():
                                   unit=m["exchange_lane_bytes"]) == lanes * per_lane
 
 
+def _static_shard_apply(ccfg, full, cache, p):
+    """One shard's ``apply_plan`` movement at the static round count."""
+    from repro.core import transmitter
+
+    full = transmitter.move_rows(cache.cached_rows, full, p.victim_slots, p.victim_rows,
+                                 p.evict_active, buffer_rows=ccfg.buffer_rows)
+    rows = transmitter.move_rows(full, cache.cached_rows, p.miss_rows, p.victim_slots,
+                                 p.load_active, buffer_rows=ccfg.buffer_rows)
+    return full, rows
+
+
+def test_sharded_apply_runs_the_busiest_shards_rounds_bit_identically():
+    """Shards with unequal load counts all run the rounds that reach the
+    busiest shard's loads: the slow tiers and arenas equal the static round
+    count's, bit for bit, and ``slab_move_rounds`` is the max over shards
+    of ceil(loads / buffer), summed over steps."""
+    tables = [col.TableConfig("big", vocab=512, dim=8, ids_per_step=32, cache_ratio=0.2),
+              col.TableConfig("small", vocab=96, dim=8, ids_per_step=32, cache_ratio=0.3)]
+    sc = ShardedEmbeddingCollection.create(tables, num_shards=4, cache_ratio=0.2,
+                                           buffer_rows=4)
+    state = sc.init(jax.random.PRNGKey(0))
+    spec = sc.cached_slabs[col.SHARED_ARENA]
+    ccfg = sc.shard_cache_config(spec)
+    static = jax.jit(jax.vmap(lambda f, c, p: _static_shard_apply(ccfg, f, c, p)))
+    plan_j, apply_j = jax.jit(sc.plan_prepare), jax.jit(sc.apply_plan)
+    rounds, unequal = 0, False
+    for i in range(5):
+        fb = rand_fb(tables, 32, seed=300 + i % 3)  # steps 3, 4 repeat: fewer loads
+        plan = plan_j(state, fb)
+        p = plan.slab_plans[col.SHARED_ARENA]
+        loads = np.asarray(p.load_active).sum(-1)
+        assert loads.shape == (4,)
+        unequal |= len(set(loads.tolist())) > 1
+        slab = state.slabs[col.SHARED_ARENA]
+        want = static(slab.full, slab.cache, p)
+        state = apply_j(state, plan)
+        got_slab = state.slabs[col.SHARED_ARENA]
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves((got_slab.full, got_slab.cache.cached_rows))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        rounds += -(-int(loads.max()) // 4)
+        np.testing.assert_array_equal(np.asarray(got_slab.cache.move_rounds), rounds)
+    assert unequal and rounds < 5 * (p.load_active.shape[-1] // 4)
+    assert int(sc.metrics(state)["slab_move_rounds"][col.SHARED_ARENA]) == rounds
+
+
 def test_device_budget_mode_composes_with_sharding():
     """A budget plan (DEVICE + CACHED mix) shards only the cached slabs;
     DEVICE tables replicate and the whole thing stays exact."""
@@ -452,6 +498,93 @@ def test_sharded_collection_on_4_device_mesh_matches_reference():
         print("SHARDED_MESH_EXACT")
     """)
     assert "SHARDED_MESH_EXACT" in out
+
+
+def test_sharded_movement_loop_predicate_is_not_batched_on_4_device_mesh():
+    """On a (data=1, model=4) mesh the jitted train step's movement loops
+    take one trip count for all shards: every ``while`` predicate is a
+    scalar, and the compiled step holds no ``select`` over a shard's slow
+    tier.  The control, a trip count taken inside the shards' ``vmap``,
+    shows both: a batched predicate and that ``select``.  Run for a few
+    steps, the mesh gives the single-device losses bit for bit."""
+    out = run_sub("""
+        import dataclasses
+        import re
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.extend import core as jcore
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        import repro.dist.partitioning as dist
+        from repro.core import cache as cache_lib
+        from repro.launch.mesh import make_hybrid_mesh
+        from repro.data import synth
+        from repro.models.dlrm import DLRM, DLRMConfig
+
+        def preds(jaxpr):
+            for e in jaxpr.eqns:
+                if e.primitive.name == "while":
+                    yield e.params["cond_jaxpr"].out_avals[0].shape
+                for v in e.params.values():
+                    for sub in v if isinstance(v, (tuple, list)) else (v,):
+                        if isinstance(sub, jcore.ClosedJaxpr):
+                            yield from preds(sub.jaxpr)
+                        elif isinstance(sub, jcore.Jaxpr):
+                            yield from preds(sub)
+
+        cfg = DLRMConfig(vocab_sizes=(2048, 256), embed_dim=8, batch_size=16,
+                         cache_ratio=0.15, lr=0.2, bottom_mlp=(16, 8), top_mlp=(16,),
+                         model_shards=4, buffer_rows=2)
+        spec = synth.ZipfSparseSpec(vocab_sizes=cfg.vocab_sizes, n_dense=13)
+        batch = {k: jnp.asarray(v) for k, v in synth.sparse_batch(spec, 16, 0, 0).items()}
+        model = DLRM(cfg)
+        state = model.init(jax.random.PRNGKey(0))
+        mesh = make_hybrid_mesh(4)
+        sh = lambda t: jax.tree_util.tree_map(
+            lambda p: NamedSharding(mesh, p), t, is_leaf=lambda x: isinstance(x, P))
+        sspecs = {"params": jax.tree_util.tree_map(lambda _: P(), state["params"]),
+                  "opt": jax.tree_util.tree_map(lambda _: P(), state["opt"]),
+                  "emb": model.collection.shard_specs(), "step": P()}
+        bspecs = {"dense": P("data", None), "sparse": P("data", None),
+                  "label": P("data")}
+        state = jax.device_put(state, sh(sspecs))
+        slab = state["emb"].slabs["__shared__"]
+        S, R, D = slab.full.data["weight"].shape
+        per_dev = re.compile(re.escape("= f32[1,%d,%d]" % (R, D)) + "[^ ]* select[(]")
+        with dist.axis_rules(mesh, dist.hybrid_rules()):
+            step = jax.jit(model.train_step, in_shardings=(sh(sspecs), sh(bspecs)))
+            got = list(preds(jax.make_jaxpr(model.train_step)(state, batch).jaxpr))
+            hlo = step.lower(state, batch).compile().as_text()
+            plan = jax.jit(model.collection.plan_prepare)(
+                state["emb"], model.features(batch))
+            p = plan.slab_plans["__shared__"]
+            ccfg = model.collection.shard_cache_config(
+                model.collection.cached_slabs["__shared__"])
+            inside = lambda f, c, q: jax.vmap(
+                lambda a, b, r: cache_lib.apply_plan(ccfg, a, b, r))(f, c, q)
+            ctl = list(preds(jax.make_jaxpr(inside)(slab.full, slab.cache, p).jaxpr))
+            ctl_hlo = jax.jit(inside).lower(slab.full, slab.cache, p).compile().as_text()
+        assert S == 4 and len(got) >= 2 and all(s == () for s in got), got
+        assert not per_dev.search(hlo)
+        assert ctl and all(s == (4,) for s in ctl), ctl
+        assert per_dev.search(ctl_hlo), "the control shows no select over the slow tier"
+        # several rounds a step, shards loading unequal counts: the mesh keeps
+        # the single-device loss trajectory bit for bit
+        ref = DLRM(dataclasses.replace(cfg, model_shards=0))
+        rs, rstep = ref.init(jax.random.PRNGKey(0)), jax.jit(ref.train_step)
+        make = lambda i: {k: jnp.asarray(v)
+                          for k, v in synth.sparse_batch(spec, 16, 0, i).items()}
+        unequal = False
+        with dist.axis_rules(mesh, dist.hybrid_rules()):
+            for i in range(4):
+                before = np.asarray(state["emb"].slabs["__shared__"].cache.misses)
+                state, m = step(state, make(i))
+                loads = np.asarray(state["emb"].slabs["__shared__"].cache.misses) - before
+                unequal |= len(set(loads.tolist())) > 1
+                rs, rm = rstep(rs, make(i))
+                assert float(m["loss"]) == float(rm["loss"]), i
+        assert unequal and int(m["slab_move_rounds"]["__shared__"]) > 4
+        print("SCALAR_TRIP_COUNT", len(got))
+    """)
+    assert "SCALAR_TRIP_COUNT" in out
 
 
 @pytest.mark.parametrize("dim", [128, 18, 7])
