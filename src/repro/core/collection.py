@@ -25,6 +25,14 @@ This module generalizes the paper's design to that setting:
                            ``gather`` / ``pool`` / ``apply_grads`` /
                            ``flush`` / ``shard_specs`` / ``device_bytes``.
 
+Where the slow tiers live follows from the device's memory: on the device
+while it holds them beside the fast tiers and a step's buffers
+(``device_need``), else in host memory (``slow_tier_memory``).  The device's
+memory is ``device_bytes_limit()``; where the backend reports none (the
+CPU) the slow tiers stay on the device.  The planner's ``budget_bytes``
+bounds the fast tiers only.  Rows the host tier cannot move
+(``host_store.host_row_ok``) make such a collection raise instead.
+
 Everything rides on the existing machinery: ``core.cache`` (Algorithm 1),
 ``core.freq`` (static frequency module), ``core.transmitter``.  The cache
 remains pure data movement, so a mixed-placement collection is bit-identical
@@ -62,6 +70,13 @@ from repro.store import (
     get_codec,
     tiered_arena_bytes,
 )
+from repro.store.host_store import (
+    CHUNK_BYTES,
+    MIN_ROW_BYTES,
+    PINNED_HOST,
+    draw_uniform,
+    host_row_ok,
+)
 
 __all__ = [
     "Placement",
@@ -79,6 +94,14 @@ __all__ = [
 ]
 
 SHARED_ARENA = "__shared__"
+
+
+def device_bytes_limit() -> Optional[int]:
+    """The default device's memory limit (``memory_stats()["bytes_limit"]``);
+    None where the backend reports none, as the CPU does."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) if limit else None
 
 # The exact-counter contract of a ``metrics()`` dict: every per-slab
 # cumulative counter (and its static per-unit byte size) that
@@ -915,6 +938,46 @@ class EmbeddingCollection:
             offs = spec.offsets
             for t, off in zip(spec.tables, offs):
                 self.table_slab[t.name] = (sname, int(off))
+        # "device" or "pinned_host": where ``init`` puts every slow tier
+        self.slow_tier_memory = self._slow_tier_memory()
+
+    def step_bytes(self) -> int:
+        """Device bytes a train step needs beside the state: every lane's
+        gathered row and its gradient, and each cached slab's staging block
+        each way."""
+        n = 0
+        for t in self.tables.values():
+            n += 2 * t.ids_per_step * t.dim * jnp.dtype(t.dtype).itemsize
+        for spec in self.cached_slabs.values():
+            rows = min(spec.buffer_rows, spec.unique_size())
+            n += 2 * rows * spec.dim * jnp.dtype(spec.dtype).itemsize
+        return n
+
+    def device_need(self) -> int:
+        """Device bytes with the slow tiers on the device: the state's fast
+        tiers and index arrays, the slow tiers, and a step's buffers."""
+        db = self.device_bytes()
+        return db["device_total"] + db["slow_tier_bytes"] + self.step_bytes()
+
+    def _slow_tier_memory(self) -> str:
+        limit = device_bytes_limit()
+        if not self.cached_slabs or limit is None or self.device_need() <= limit:
+            return "device"
+        for sname, spec in self.cached_slabs.items():
+            codec = get_codec(self._slab_codec(sname))
+            widths = [spec.dim * jnp.dtype(codec.payload_dtype(spec.dtype)).itemsize]
+            if codec.sideband_row_shape() is not None:
+                widths.append(int(np.prod(codec.sideband_row_shape())) * 4)
+            if not all(host_row_ok(w) for w in widths):
+                raise ValueError(
+                    f"the slow tiers need {self.device_need()} B of device memory beside "
+                    f"the rest, over the device's {limit} B, and slab {sname!r} cannot "
+                    f"go to the host tier (pinned_host memory): its {codec.name} rows "
+                    f"are {widths} B wide, and the host tier takes rows of "
+                    f"{MIN_ROW_BYTES} B or wider that fill {CHUNK_BYTES} B a whole number "
+                    f"of times (fp32 rows of 128, 256, 512 or 1024 values); use such "
+                    f"rows, more model shards or a larger device")
+        return PINNED_HOST
 
     # ----- construction -----------------------------------------------------
 
@@ -987,9 +1050,11 @@ class EmbeddingCollection:
             )
         for sname, spec in self.cached_slabs.items():
             scale = 1.0 / np.sqrt(spec.dim)
-            weight = jax.random.uniform(
-                next(kit), (spec.vocab, spec.dim), spec.dtype, -scale, scale
-            )
+            k_slab = next(kit)
+            if self.slow_tier_memory != PINNED_HOST:
+                weight = jax.random.uniform(
+                    k_slab, (spec.vocab, spec.dim), spec.dtype, -scale, scale
+                )
             slab_counts = None
             if counts is not None:
                 slab_counts = np.concatenate(
@@ -1030,8 +1095,14 @@ class EmbeddingCollection:
                 spec = dataclasses.replace(spec, arena_precision=arena_codec)
                 self.cached_slabs[sname] = spec
             self.arena_precision[sname] = arena_codec
+            if self.slow_tier_memory == PINNED_HOST:
+                # drawn a staging block at a time, so no device holds it whole
+                full = draw_uniform(k_slab, spec.vocab, spec.dim, spec.dtype,
+                                    -scale, scale, spec.buffer_rows, codec)
+            else:
+                full = HostStore.create({"weight": weight}, codec=codec)
             slab = CachedSlab(
-                full=HostStore.create({"weight": weight}, codec=codec),
+                full=full,
                 cache=cache_lib.init_cache(
                     spec.cache_config(), {"weight": jnp.zeros((spec.dim,), spec.dtype)}
                 ),
@@ -1644,7 +1715,8 @@ class EmbeddingCollection:
                 )
             slabs[sname] = CachedSlab(
                 full=HostStore.spec_like(
-                    like, {"weight": full_w}, side_w, codec=self._slab_codec(sname)
+                    like, {"weight": full_w}, side_w, codec=self._slab_codec(sname),
+                    memory_kind=self.slow_tier_memory,
                 ),
                 cache=cache_lib.CacheState(
                     cached_rows=cached_rows,

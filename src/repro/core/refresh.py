@@ -263,6 +263,11 @@ def refresh_cached_slab(
     only the invalidate+permute runs.  Returns ``(slab', stats)``; a no-swap
     pass returns the slab unchanged.
     """
+    if isinstance(slab.full, HostStore) and slab.full.on_host:
+        # ``_apply_swaps`` returns a new slow tier (it donates nothing): a
+        # host-resident table would be held twice in host memory
+        raise ValueError("refresh swaps rows of a slow tier on the device; this "
+                         "slab's slow tier is the host tier (pinned_host memory)")
     cache = slab.cache
     capacity = int(cache.slot_to_row.shape[0])
     vocab = int(cache.row_to_slot.shape[0])

@@ -61,13 +61,11 @@ Scaling the exchange (the three fronts that keep throughput monotone in S):
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.extend.random import threefry2x32_p
 
 from repro.analysis.contracts import INT_COUNTERS, contract
 from repro.core import cache as cache_lib
@@ -87,10 +85,12 @@ from repro.core.collection import (
     TableConfig,
     _CachedSlabSpec,
     _read_full_rows,
+    device_bytes_limit,
 )
 from repro.dist import partitioning as dist_part
 from repro.kernels.cache_ops import ops as cache_ops
 from repro.obs.tracing import SCOPE_EXCHANGE
+from repro.store.host_store import row_counters, uniform_rows  # noqa: F401 (public here too)
 from repro.store import (
     ArenaStore,
     HostStore,
@@ -120,41 +120,6 @@ def _draws_by_counter(dtype) -> bool:
     """``uniform_rows`` reproduces ``jax.random.uniform`` only for float32
     under JAX's partitionable threefry (its default)."""
     return bool(jax.config.jax_threefry_partitionable) and jnp.dtype(dtype) == jnp.float32
-
-
-def row_counters(rows: jnp.ndarray, dim: int):
-    """``(hi, lo)`` uint32 words of the 64-bit counters ``r * dim + j`` of
-    rows ``rows`` (shape ``rows.shape + (dim,)``), from 16-bit limbs of r."""
-    if not 0 < dim < 1 << 16:
-        raise ValueError(f"dim {dim} outside the 16-bit counter split")
-    r = rows.astype(jnp.uint32)[..., None]
-    j = jnp.arange(dim, dtype=jnp.uint32)
-    d = jnp.uint32(dim)
-    p_lo = (r & 0xFFFF) * d
-    p_hi = (r >> 16) * d
-    t = (p_hi & 0xFFFF) << 16
-    s1 = t + p_lo
-    lo = s1 + j
-    hi = (p_hi >> 16) + (s1 < t).astype(jnp.uint32) + (lo < s1).astype(jnp.uint32)
-    return hi, lo
-
-
-@functools.partial(jax.jit, static_argnames=("dim",))
-def uniform_rows(key, rows: jnp.ndarray, dim: int, minval: float, maxval: float):
-    """Rows ``rows`` (int32, any shape) of ``jax.random.uniform(key, (vocab,
-    dim), jnp.float32, minval, maxval)``, bit for bit, without drawing the
-    others.  Partitionable threefry draws element ``(r, j)`` from the 64-bit
-    counter ``r * dim + j``, so each device can draw just the rows it holds.
-    Jitted like ``jax.random.uniform``, so both compile the same arithmetic."""
-    data = jax.random.key_data(key) if jax.dtypes.issubdtype(
-        key.dtype, jax.dtypes.prng_key) else key
-    hi, lo = row_counters(rows, dim)
-    b1, b2 = threefry2x32_p.bind(data[0], data[1], hi, lo)
-    # jax.random.uniform's float32 transform of the bits
-    one = jnp.asarray(np.array(1.0, np.float32).view(np.uint32))
-    floats = jax.lax.bitcast_convert_type(((b1 ^ b2) >> 9) | one, jnp.float32) - 1.0
-    lo_v, hi_v = jnp.float32(minval), jnp.float32(maxval)
-    return jnp.maximum(lo_v, floats * (hi_v - lo_v) + lo_v)
 
 
 def flat_store(store: HostStore) -> HostStore:
@@ -367,6 +332,24 @@ class ShardedEmbeddingCollection(EmbeddingCollection):
         # (it needs the counts), updated by re-balance passes, and mirrored
         # host-side for telemetry.
         self.assignments: Dict[str, ShardAssignment] = {}
+        limit = device_bytes_limit()
+        if self.cached_slabs and limit is not None and self.device_need() > limit:
+            raise ValueError(
+                f"the sharded collection needs {self.device_need()} B per device with "
+                f"each shard's slow tier there, over the device's {limit} B; it has no "
+                f"host tier (the slow tier in pinned_host memory serves the unsharded "
+                f"collection only): use more model shards or devices with more memory")
+
+    def _slow_tier_memory(self) -> str:
+        return "device"  # the shard slow tiers stay on their devices (checked above)
+
+    def device_need(self) -> int:
+        """Bytes one mesh device needs with its shard of every slow tier: its
+        share of the state (``device_per_shard``), its slow-tier shard and a
+        step's buffers."""
+        db = self.device_bytes()
+        return db["device_per_shard"] + db["slow_tier_bytes"] // self.num_shards \
+            + self.step_bytes()
 
     @classmethod
     def create(

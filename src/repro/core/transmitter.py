@@ -28,7 +28,10 @@ stage then gathers the *encoded* payload + sideband into the staging block —
 that is what crosses the slow link, so an int8 store moves ~4x fewer bytes
 per round — and the decode (load) / encode (writeback) runs on the block at
 the device end of the link.  With the fp32 codec the store is raw arrays and
-the path is bit-identical to the plain-pytree one.
+the path is bit-identical to the plain-pytree one.  A host-resident store
+(``memory_kind == "pinned_host"``) moves a round's rows in 4 KiB chunks by
+DMA (``HostStore.gather_block`` / ``scatter_block``), over the lanes that
+may be active only (``live_lanes``), and chunk staging is off for it.
 
 The DEVICE side may likewise be a :class:`repro.store.ArenaStore` (the
 frequency-tiered arena): gathers decode-on-read (head slots bit-exact, tail
@@ -40,6 +43,7 @@ decode, since the head stores fp32).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -174,9 +178,11 @@ def _scatter_store_rows_chunked(
         if store.sideband
         else store.sideband
     )
-    return HostStore(
-        data=data, sideband=sideband, codec=store.codec, out_dtype=store.out_dtype
-    )
+    return dataclasses.replace(store, data=data, sideband=sideband)
+
+
+def _on_host(tree: Any) -> bool:
+    return isinstance(tree, HostStore) and tree.on_host
 
 
 def gather_rows(tree: Any, idx: jnp.ndarray) -> Any:
@@ -213,19 +219,29 @@ def _gather_store_rows(store: HostStore, idx: jnp.ndarray) -> Any:
     return store.decode_block(block, side)
 
 
+def _pack_store_rows(store: HostStore, idx: jnp.ndarray, count) -> Any:
+    """Pack from a host store: the ENCODED payload + sideband staging block
+    (this is what crosses the link).  A host-resident store moves only the
+    round's first ``count`` lanes."""
+    if store.on_host:
+        return store.gather_block(idx, count)
+    return gather_rows(store.data, idx), gather_rows(store.sideband, idx)
+
+
 def _scatter_store_rows(
-    store: HostStore, idx: jnp.ndarray, block: Any, active: jnp.ndarray
+    store: HostStore, idx: jnp.ndarray, block: Any, active: jnp.ndarray, count=None
 ) -> HostStore:
     """Unpack into a host store: quantize-on-writeback — the block is encoded
-    on the device side, then payload + sideband cross the link and scatter."""
+    on the device side, then payload + sideband cross the link and scatter.
+    A host-resident store moves only the round's first ``count`` lanes."""
     data_blk, side_blk = store.encode_block(block)
+    if store.on_host:
+        return store.scatter_block(idx, data_blk, side_blk, active, count)
     data = scatter_rows(store.data, idx, data_blk, active)
     sideband = (  # sideband-free codecs (fp32/fp16) carry an empty dict
         scatter_rows(store.sideband, idx, side_blk, active) if store.sideband else store.sideband
     )
-    return HostStore(
-        data=data, sideband=sideband, codec=store.codec, out_dtype=store.out_dtype
-    )
+    return dataclasses.replace(store, data=data, sideband=sideband)
 
 
 def move_rows(
@@ -279,15 +295,26 @@ def move_rows(
     src_store = src_tree.data if isinstance(src_tree, HostStore) else src_tree
     chunk_src = (
         src_chunk_rows
-        if not isinstance(src_tree, ArenaStore) and _chunkable(src_store, src_chunk_rows)
+        if not isinstance(src_tree, ArenaStore) and not _on_host(src_tree)
+        and _chunkable(src_store, src_chunk_rows)
         else 0
     )
     dst_store = dst_tree.data if isinstance(dst_tree, HostStore) else dst_tree
     chunk_dst = (
         dst_chunk_rows
-        if not isinstance(dst_tree, ArenaStore) and _chunkable(dst_store, dst_chunk_rows)
+        if not isinstance(dst_tree, ArenaStore) and not _on_host(dst_tree)
+        and _chunkable(dst_store, dst_chunk_rows)
         else 0
     )
+    if _on_host(src_tree):
+        src_tree = src_tree.placed()
+    if _on_host(dst_tree):
+        dst_tree = dst_tree.placed()  # the loop carries it host-typed
+
+    def lanes(r):  # of round r: its lanes a host-resident side moves
+        if live_lanes is None:
+            return buffer_rows
+        return jnp.clip(live_lanes - r * buffer_rows, 0, buffer_rows)
 
     def body(r, dst):
         s = r * buffer_rows
@@ -304,8 +331,7 @@ def move_rows(
                 # keep the encoded block around: if the destination is a
                 # tiered arena of the same codec, tail lanes take it
                 # verbatim below.
-                enc_payload = gather_rows(src_tree.data, si)
-                enc_side = gather_rows(src_tree.sideband, si)
+                enc_payload, enc_side = _pack_store_rows(src_tree, si, lanes(r))
                 block = src_tree.decode_block(enc_payload, enc_side)
         elif isinstance(src_tree, ArenaStore):  # pack + decode-on-read
             block = src_tree.gather_slots(si)
@@ -316,7 +342,7 @@ def move_rows(
         if isinstance(dst, HostStore):  # encode-on-writeback + unpack
             if chunk_dst:
                 return _scatter_store_rows_chunked(dst, di, block, ac, chunk_dst)
-            return _scatter_store_rows(dst, di, block, ac)
+            return _scatter_store_rows(dst, di, block, ac, lanes(r))
         if isinstance(dst, ArenaStore):  # tiered unpack (tail encodes)
             payload_blk = side_blk = None
             if enc_payload is not None and isinstance(src_tree, HostStore) \

@@ -3,7 +3,9 @@ fault-tolerant trainer on synthetic data.
 
 ``dlrm-avazu`` and ``dlrm-criteo`` build the registry configs at their
 published widths (dim 128, the paper's MLPs, batch, 1.5% cache); ``dlrm``
-and the other recsys archs build small CPU-scale models.  With
+and the other recsys archs build small CPU-scale models.  A slow tier that
+does not fit the device's memory lives in host memory (``dlrm-criteo`` on
+one v5e); the run's last lines say where it lived.  With
 ``--model-shards N`` on a host with at least N devices the cached slabs
 split over the ``model`` axis of a hybrid (data, model) mesh.
 
@@ -356,7 +358,7 @@ def main():
             tc, step_fn=where.jit(model.train_step, donate_argnums=(0,)), **kw
         )
     with where.scope:
-        trainer.run()
+        state = trainer.run()
     h = trainer.history
     # history may be trimmed to a tail (--history-limit): report real steps
     print(f"\narch={args.arch} steps={h[-1]['step'] + 1} "
@@ -369,8 +371,12 @@ def main():
               f"window hit rate {h[-1].get('window_hit_rate', 0.0):.1%}")
     if hasattr(model, "collection"):
         db = model.collection.device_bytes()
+        kinds = sorted({getattr(leaf.sharding, "memory_kind", None)
+                        for slab in state["emb"].slabs.values() if hasattr(slab, "full")
+                        for leaf in jax.tree_util.tree_leaves(slab.full)})
         print(f"host tier ({args.host_precision}): {db['slow_tier_bytes']/1e6:.1f} MB "
-              f"(saved {db['host_bytes_saved']/1e6:.1f} MB vs fp32)")
+              f"(saved {db['host_bytes_saved']/1e6:.1f} MB vs fp32) in "
+              f"{model.collection.slow_tier_memory} memory (its arrays: {kinds})")
         if args.arena_precision != "fp32":
             print(f"arena tier ({args.arena_precision}): saved "
                   f"{db.get('arena_bytes_saved', 0)/1e6:.2f} MB HBM vs fp32")

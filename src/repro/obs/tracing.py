@@ -45,7 +45,10 @@ __all__ = ["Tracer", "NULL_TRACER", "STEP_SCOPES"]
 # profiler trace splits device time by layer.  Two nest inside others:
 # ``telemetry`` also names the plan's id-level hit probe
 # (``core.cache.plan_prepare``), ``shard.exchange`` the sharded lookup's
-# cross-shard leg (``core.sharded``).
+# cross-shard leg (``core.sharded``).  ``cache.host_link`` names the legs
+# that cross the host<->device link when the slow tier lives in host memory
+# (``core.transmitter``; nested in ``cache.movement`` in the step); it is
+# not in ``STEP_SCOPES`` because a step whose slow tier is in HBM has none.
 SCOPE_PLAN = "cache.plan"
 SCOPE_MOVEMENT = "cache.movement"
 SCOPE_LOOKUP = "cache.lookup"
@@ -53,6 +56,7 @@ SCOPE_EXCHANGE = "shard.exchange"
 SCOPE_DENSE = "dense"
 SCOPE_ROW_UPDATE = "cache.row_update"
 SCOPE_TELEMETRY = "telemetry"
+SCOPE_HOST_LINK = "cache.host_link"
 STEP_SCOPES = (SCOPE_PLAN, SCOPE_MOVEMENT, SCOPE_LOOKUP, SCOPE_EXCHANGE,
                SCOPE_DENSE, SCOPE_ROW_UPDATE, SCOPE_TELEMETRY)
 

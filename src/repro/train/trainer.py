@@ -148,9 +148,14 @@ class Trainer:
         if self.checkpointer is not None:
             try:
                 restored, start = self.checkpointer.restore_latest(state)
-                state = restored
                 if self.shard_fn is not None:
-                    state = self.shard_fn(state)  # elastic: new mesh, same logical state
+                    state = self.shard_fn(restored)  # elastic: new mesh, same logical state
+                else:
+                    # each leaf where the fresh state holds it (its memory
+                    # kind included: a slow tier in host memory stays there)
+                    state = jax.tree_util.tree_map(
+                        lambda r, x: jax.device_put(r, x.sharding)
+                        if isinstance(x, jax.Array) else r, restored, state)
             except FileNotFoundError:
                 pass
         return state, start

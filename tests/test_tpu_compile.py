@@ -106,3 +106,46 @@ def test_fm_interaction_compiles(one_chip):
     fn = functools.partial(fm_interaction_pallas, interpret=False)
     compiled = _compile(fn, one_chip, ((cfg.batch_size, f, d), jnp.float32))
     assert compiled.memory_analysis() is not None
+
+
+def test_host_tier_step_keeps_its_table_in_host_memory(topo, monkeypatch):
+    """A DLRM step whose slow tier is in host memory, in two pieces moved by
+    the host leg's Pallas kernels: the chip's compiler updates the 2 GB
+    table in place in host memory and stages none of it in HBM; so do the
+    flush and a read of a few rows."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import collection as col
+    from repro.models.dlrm import DLRMConfig
+    from repro.store import host_store
+
+    monkeypatch.setattr(col, "device_bytes_limit", lambda: 1 << 30)  # a 1 GiB device
+    monkeypatch.setattr(host_store, "PIECE_BYTES", 1 << 30)
+    monkeypatch.setattr(host_store, "interpret_mode", lambda *a: False)  # the chip's kernels
+    cfg = DLRMConfig(vocab_sizes=(4_000_000, 1000), embed_dim=128, batch_size=1024,
+                     bottom_mlp=(128,), top_mlp=(64,), buffer_rows=512)
+    model = DLRM(cfg)
+    assert model.collection.slow_tier_memory == "pinned_host"
+    mesh = Mesh(np.array(topo.devices[:1]), ("x",))
+    host = NamedSharding(mesh, P(), memory_kind="pinned_host")
+    dev = NamedSharding(mesh, P(), memory_kind="device")
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert len(shapes["emb"].slabs[SHARED_ARENA].full.data["weight"].pieces) == 2
+    state = jax.tree_util.tree_map_with_path(
+        lambda path, x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=host if ".full" in jax.tree_util.keystr(path) else dev),
+        shapes)
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=dev)
+             for k, v in model.input_specs(cfg.batch_size).items()}
+    table = 4_001_000 * 128 * 4
+    for fn in (model.train_step, model.flush):
+        args = (state, batch) if fn == model.train_step else (state,)
+        ma = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile().memory_analysis()
+        assert ma.host_alias_size_in_bytes >= table
+        # the legs' device blocks (160 MiB at least each), no copy of the table
+        assert ma.temp_size_in_bytes < table // 4
+    ids = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=dev)
+    read = jax.jit(lambda e, i: model.collection.full_lookup(e, "f0", i)).lower(
+        state["emb"], ids).compile()
+    assert read.memory_analysis().temp_size_in_bytes < table // 4
